@@ -97,22 +97,27 @@ def _encode_row(reps: int = 20) -> Dict:
 
 
 def main(scale=None, full: bool = False) -> list:
-    import tempfile
-
     import jax
 
+    from repro.common.compile_cache import configure_compile_cache
     from repro.exp import Experiment
     from repro.launch.gossip import fleet_summary, launch_gossip
 
+    # the gossip children run on the CPU (launch/gossip.py); so does this
+    # process, so that the two rows' wall_s compare like with like and
+    # the sim row warms the cache the socket ranks load
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "this process already holds a "
+            f"{jax.default_backend()!r} backend; the socket rows compare "
+            "against CPU gossip ranks, so run them in a process of their "
+            "own: python -m benchmarks.run --only socket")
     # one persistent compilation cache shared by this process AND every
-    # gossip child (launch_gossip exports the same default): the sim row
-    # warms it, the socket ranks reuse it instead of recompiling the same
+    # gossip child (they resolve the same directory): the sim row warms
+    # it, the socket ranks reuse it instead of recompiling the same
     # distill step per process — the bulk of the historical 3.5× gap
-    cache_dir = os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "repro_jit_cache"))
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    configure_compile_cache()
 
     steps = 40 if full else 16
     out, bench_rows = [], []
@@ -132,6 +137,7 @@ def main(scale=None, full: bool = False) -> list:
     edges = max(len(meter.by_edge), 1)
     sim = {
         "name": "socket/simulated_inprocess",
+        "platform": jax.default_backend(),
         "transport": "simulated",
         "ticks": steps,
         "wall_s": round(sim_wall, 2),
@@ -154,6 +160,8 @@ def main(scale=None, full: bool = False) -> list:
     overhead = max(sock_wall - fleet["wall_seconds_max"], 0.0)
     sock = {
         "name": "socket/tcp_multiprocess",
+        # the gossip children always run on the CPU (launch/gossip.py)
+        "platform": results[0]["platform"],
         "transport": "socket",
         "ticks": steps,
         # wall_s is NET of launcher overhead (process spawn, rendezvous,

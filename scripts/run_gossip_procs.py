@@ -103,6 +103,10 @@ def main(argv=None) -> int:
                         "contributed distill spans, flow coverage)")
     args = p.parse_args(argv)
 
+    # the wire's CPU contract test: the children run on the CPU
+    # (launch/gossip.py), and so does the in-process cache warm-up, so
+    # that what it compiles is what they load
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro.exp import ExperimentSpec, get_preset
     from repro.launch.gossip import fleet_summary, launch_gossip
 
@@ -139,9 +143,6 @@ def main(argv=None) -> int:
         # cold CI containers would pay the full per-child jit compile
         # inside the launch timeout; warm the shared persistent cache
         # in-process first so the children load instead of compiling
-        os.environ.setdefault(
-            "JAX_COMPILATION_CACHE_DIR",
-            os.path.join(tempfile.gettempdir(), "repro_jit_cache"))
         _warm_jit_cache(spec)
 
     K = spec.num_clients
@@ -263,13 +264,10 @@ def _warm_jit_cache(spec) -> None:
     (the socket smoke's 2, the churn smoke's two 3-process fleets) then
     loads instead of compiling, which is what keeps the smokes inside
     the CI budget."""
-    import jax
-
+    from repro.common.compile_cache import configure_compile_cache
     from repro.exp import Experiment, TransportSpec
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    configure_compile_cache()
     warm = dataclasses.replace(
         spec, name="churn_smoke_warm",
         transport=TransportSpec(kind="loopback"),
@@ -320,9 +318,6 @@ def scoreboard_smoke(straggler: int = 2) -> int:
                               pace_ms=(0.0, 0.0, slow_pace_ms)),
         train=dataclasses.replace(spec.train, steps=16))
     spec.validate()
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "repro_jit_cache"))
     # warm with a sync schedule: the jitted computations are identical,
     # and the warm run needs no pacer
     _warm_jit_cache(dataclasses.replace(spec, schedule=ScheduleSpec()))
@@ -394,9 +389,6 @@ def lm_smoke() -> int:
         spec, name="lm_smoke",
         train=dataclasses.replace(spec.train, steps=12))
     spec.validate()
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "repro_jit_cache"))
     _warm_jit_cache(spec)
 
     print(f"lm smoke: 3 processes "
@@ -465,12 +457,10 @@ def churn_smoke(crash_rank: int = 1, crash_step: int = 5) -> int:
     from repro.launch.gossip import fleet_summary, launch_gossip
 
     snap_dir = tempfile.mkdtemp(prefix="fleet_churn_smoke_")
-    # jit cache shared by every child of both launches: the resumed fleet
-    # (and ranks 1..2 of the first) skip compilation — what keeps two
-    # full 3-process launches inside the CI budget
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(snap_dir, "jit_cache"))
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    # every child of both launches shares the persistent compile cache
+    # (repro.common.compile_cache): the resumed fleet (and ranks 1..2 of
+    # the first) skip compilation — what keeps two full 3-process
+    # launches inside the CI budget
     spec = get_preset("gossip_socket")
     spec = dataclasses.replace(
         spec,

@@ -180,14 +180,18 @@ def _distill_loss_one_client(student, teacher, mhd: MHDConfig,
     """Eqs. (2),(4),(5) against ONE ring teacher (Δ=1 in the pod runtime).
 
     student: dense outputs; teacher: dense or top-k-packed (already
-    stop-gradiented).
+    stop-gradiented). Returns the loss and its terms: the embedding term
+    ``emb`` and, stacked over aux heads (m, B), the Eq. 4 choice
+    ``use_teacher``, the two confidences it compared and the chosen CE
+    ``per_sample``.
     """
-    from repro.core.mhd import embedding_distillation_loss, _confidence
+    from repro.core.mhd import embedding_distillation_loss
 
     total = jnp.zeros((), jnp.float32)
     emb = embedding_distillation_loss(
         student["embedding"], teacher["embedding"][None], mhd.nu_emb)
 
+    heads = []
     m = mhd.num_aux_heads
     for k in range(1, m + 1):
         student_head = student["aux_logits"][k - 1]
@@ -211,7 +215,12 @@ def _distill_loss_one_client(student, teacher, mhd: MHDConfig,
         use_teacher = conf_t >= conf_s  # Eq. 4 argmax over {teacher, self}
         per_sample = jnp.where(use_teacher, ce_t, ce_s)
         total = total + jnp.mean(per_sample)
-    return mhd.nu_aux * total + emb
+        heads.append({"use_teacher": use_teacher, "conf_teacher": conf_t,
+                      "conf_self": conf_s, "per_sample": per_sample})
+    terms = {"emb": emb}
+    if heads:
+        terms.update(jax.tree.map(lambda *xs: jnp.stack(xs), *heads))
+    return mhd.nu_aux * total + emb, terms
 
 
 def make_distributed_mhd_step(bundle: ModelBundle, optimizer,
@@ -220,6 +229,13 @@ def make_distributed_mhd_step(bundle: ModelBundle, optimizer,
 
     state["params"]: pytree stacked (K, ...) — shard dim 0 over 'pod'.
     batch: {"private_tokens": (K, B, T), "public_tokens": (B_pub, T)}.
+
+    The metrics hold the scalars ``loss``, ``ce`` and ``dist``, and,
+    stacked over clients (K, ...), what the exchange moved —
+    ``exchange["sent"]`` and ``exchange["received"]``, the teacher
+    predictions before and after it — and the per-position distillation
+    terms ``gate`` (see `_distill_loss_one_client`). Under jit, a caller
+    that keeps only the scalars pays for none of the rest.
     """
     K = dist.num_clients
 
@@ -262,14 +278,18 @@ def make_distributed_mhd_step(bundle: ModelBundle, optimizer,
                 wire = frozen
             teachers = _exchange_teachers(wire, dist)
 
-            dist_loss = jnp.mean(jax.vmap(
+            dist_losses, gate = jax.vmap(
                 lambda s, t: _distill_loss_one_client(s, t, mhd,
                                                       dist.exchange)
-            )(pub_pred, teachers))
+            )(pub_pred, teachers)
+            dist_loss = jnp.mean(dist_losses)
 
             aux = jnp.mean(pub_outs["aux_loss"]) + \
                 jnp.mean(priv_outs["aux_loss"])
-            return ce + dist_loss + aux, {"ce": ce, "dist": dist_loss}
+            return ce + dist_loss + aux, {
+                "ce": ce, "dist": dist_loss,
+                "exchange": {"sent": wire, "received": teachers},
+                "gate": gate}
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"])

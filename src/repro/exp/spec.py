@@ -107,6 +107,16 @@ def _resnet_tiny34(num_labels: int, aux_heads: int, width: int):
     return _RN.resnet_tiny34(num_labels, num_aux_heads=aux_heads, width=width)
 
 
+@CLIENT_ARCHS.register("resnet18")
+def _resnet18(num_labels: int, aux_heads: int, width: int):
+    return _RN.resnet18(num_labels, num_aux_heads=aux_heads, width=width)
+
+
+@CLIENT_ARCHS.register("resnet34")
+def _resnet34(num_labels: int, aux_heads: int, width: int):
+    return _RN.resnet34(num_labels, num_aux_heads=aux_heads, width=width)
+
+
 def _register_lm(arch_name: str, zoo_name: str) -> None:
     """Reduced LM zoo configs as fleet archs. ``num_labels`` carries the
     head dimension — the shared vocab of a text fleet (the runner passes

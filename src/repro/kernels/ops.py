@@ -2,15 +2,14 @@
 
 ``use_pallas`` resolution:
   * explicit argument wins;
-  * else kernels are used when the default backend is TPU (compile target),
-    and the pure-jnp reference path is used on CPU (tests / experiments).
-Set ``REPRO_FORCE_PALLAS_INTERPRET=1`` to exercise the kernel bodies on CPU
-via interpret mode (slow; the kernel test-suite does this per-kernel).
+  * else the compiled kernel runs when the default backend is TPU, and the
+    pure-jnp reference runs on every other backend.
+No wrapper interprets a kernel: interpret mode is for the kernel tests,
+which pass ``interpret=True`` to a kernel module themselves.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,25 +22,14 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel
 
 
 def _default_use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"):
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def _interpret() -> bool:
-    return bool(os.environ.get("REPRO_FORCE_PALLAS_INTERPRET")) or \
-        jax.default_backend() != "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def dist_ce(student_logits, teacher_logits, use_pallas: bool | None = None):
     """Fused distillation CE + confidences. Returns (ce, t_conf, s_conf)."""
     use = _default_use_pallas() if use_pallas is None else use_pallas
     if use:
-        return _dist_ce_kernel(student_logits, teacher_logits,
-                               interpret=_interpret())
+        return _dist_ce_kernel(student_logits, teacher_logits)
     return REF.dist_ce_ref(student_logits, teacher_logits)
 
 
@@ -49,8 +37,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     use_pallas: bool | None = None):
     use = _default_use_pallas() if use_pallas is None else use_pallas
     if use:
-        return _flash_kernel(q, k, v, causal=causal, window=window,
-                             interpret=_interpret())
+        return _flash_kernel(q, k, v, causal=causal, window=window)
     return REF.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
@@ -58,8 +45,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128,
              use_pallas: bool | None = None):
     use = _default_use_pallas() if use_pallas is None else use_pallas
     if use:
-        return _ssd_kernel(x, dt, A, B, C, D, chunk=chunk,
-                           interpret=_interpret())
+        return _ssd_kernel(x, dt, A, B, C, D, chunk=chunk)
     from repro.models.ssm import ssd_chunked
 
     return ssd_chunked(x, dt, A, B, C, D, chunk_size=chunk)
@@ -71,22 +57,21 @@ def topk_wire(logits, k: int = 32, use_pallas: bool | None = None):
     if use:
         from repro.kernels.topk_wire import topk_wire as _kernel
 
-        return _kernel(logits, k, interpret=_interpret())
+        return _kernel(logits, k)
     return REF.topk_wire_ref(logits, k)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "val_dtype", "idx_dtype", "emb_int8", "use",
-                     "interpret"))
+    static_argnames=("k", "val_dtype", "idx_dtype", "emb_int8", "use"))
 def _topk_wire_frame_jit(heads, emb, d127, *, k: int, val_dtype, idx_dtype,
-                         emb_int8: bool, use: bool, interpret: bool):
+                         emb_int8: bool, use: bool):
     W, H, B, C = heads.shape
     flat = heads.astype(jnp.float32).reshape(W * H * B, C)
     if use:
         from repro.kernels.topk_wire import topk_wire as _kernel
 
-        vals, idx, lse = _kernel(flat, k, interpret=interpret)
+        vals, idx, lse = _kernel(flat, k)
     else:
         vals, idx, lse = REF.topk_wire_ref(flat, k)
     wire_vals = vals.reshape(W, H, B, k).astype(val_dtype)
@@ -135,26 +120,23 @@ def topk_wire_frame(heads, emb, k: int, *, val_dtype: str = "float16",
         heads, emb, jnp.float32(127.0), k=k,
         val_dtype=jnp.float16 if val_dtype == "float16" else jnp.float32,
         idx_dtype=jnp.uint16 if idx_dtype == "uint16" else jnp.uint32,
-        emb_int8=(emb_encoding == "int8"), use=use,
-        interpret=_interpret())
+        emb_int8=(emb_encoding == "int8"), use=use)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("k", "k_min", "budget_bytes_per_token", "entry_bytes",
-                     "val_dtype", "idx_dtype", "emb_int8", "use",
-                     "interpret"))
+                     "val_dtype", "idx_dtype", "emb_int8", "use"))
 def _adaptive_topk_wire_frame_jit(heads, emb, d127, *, k: int, k_min: int,
                                   budget_bytes_per_token: int,
                                   entry_bytes: int, val_dtype, idx_dtype,
-                                  emb_int8: bool, use: bool,
-                                  interpret: bool):
+                                  emb_int8: bool, use: bool):
     W, H, B, C = heads.shape
     flat = heads.astype(jnp.float32).reshape(W * H * B, C)
     if use:
         from repro.kernels.topk_wire import topk_wire as _kernel
 
-        vals, idx, lse = _kernel(flat, k, interpret=interpret)
+        vals, idx, lse = _kernel(flat, k)
     else:
         vals, idx, lse = REF.topk_wire_ref(flat, k)
     wire_vals = vals.reshape(W, H, B, k).astype(val_dtype)
@@ -248,13 +230,11 @@ def adaptive_topk_wire_frame(heads, emb, k: int, *, k_min: int = 1,
         entry_bytes=entry_bytes,
         val_dtype=jnp.float16 if val_dtype == "float16" else jnp.float32,
         idx_dtype=jnp.uint16 if idx_dtype == "uint16" else jnp.uint32,
-        emb_int8=(emb_encoding == "int8"), use=use,
-        interpret=_interpret())
+        emb_int8=(emb_encoding == "int8"), use=use)
 
 
 def emb_dist(student_emb, teacher_emb, use_pallas: bool | None = None):
     use = _default_use_pallas() if use_pallas is None else use_pallas
     if use:
-        return _emb_dist_kernel(student_emb, teacher_emb,
-                                interpret=_interpret())
+        return _emb_dist_kernel(student_emb, teacher_emb)
     return REF.emb_dist_ref(student_emb, teacher_emb)

@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -25,25 +24,37 @@ _NEG = -1e30
 def _topk_wire_kernel(x_ref, vals_ref, idx_ref, lse_ref, *, k: int,
                       v_total: int):
     x = x_ref[...].astype(jnp.float32)  # (rows, V)
+    rows = x.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     x = jnp.where(col < v_total, x, _NEG)
 
-    # fused logsumexp (one pass, before masking rounds)
-    m = jnp.max(x, axis=-1)
-    lse_ref[...] = m + jnp.log(jnp.sum(jnp.exp(x - m[:, None]), axis=-1))
+    # fused logsumexp (one pass, before masking rounds); a (rows, 1)
+    # block, since Mosaic tiles a rank-1 block only at 128 lanes
+    m = jnp.max(x, axis=-1, keepdims=True)
+    lse_ref[...] = m + jnp.log(jnp.sum(jnp.exp(x - m), axis=-1,
+                                       keepdims=True))
+
+    # the k results are built in registers and stored once: Mosaic cannot
+    # store at a lane index that is only known inside the loop
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
 
     def round_fn(i, carry):
-        cur = carry
-        vmax = jnp.max(cur, axis=-1)  # (rows,)
-        hit = cur == vmax[:, None]
+        cur, vals, idx = carry
+        vmax = jnp.max(cur, axis=-1, keepdims=True)  # (rows, 1)
+        hit = cur == vmax
         # first index achieving the max
-        imax = jnp.min(jnp.where(hit, col, v_total), axis=-1)
-        vals_ref[:, i] = vmax
-        idx_ref[:, i] = imax
-        cur = jnp.where(col == imax[:, None], _NEG, cur)
-        return cur
+        imax = jnp.min(jnp.where(hit, col, v_total), axis=-1, keepdims=True)
+        vals = jnp.where(lane == i, vmax, vals)
+        idx = jnp.where(lane == i, imax, idx)
+        cur = jnp.where(col == imax, _NEG, cur)
+        return cur, vals, idx
 
-    jax.lax.fori_loop(0, k, round_fn, x)
+    _, vals, idx = jax.lax.fori_loop(
+        0, k, round_fn,
+        (x, jnp.zeros((rows, k), jnp.float32), jnp.zeros((rows, k),
+                                                         jnp.int32)))
+    vals_ref[...] = vals
+    idx_ref[...] = idx
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
@@ -64,13 +75,13 @@ def topk_wire(logits, k: int = 32, *, block_rows: int = 8,
         out_specs=[
             pl.BlockSpec((rows, k), lambda i: (i, 0)),
             pl.BlockSpec((rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((rows,), lambda i: (i,)),
+            pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, k), jnp.float32),
             jax.ShapeDtypeStruct((Bp, k), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(logits)
-    return vals[:B], idx[:B], lse[:B]
+    return vals[:B], idx[:B], lse[:B, 0]

@@ -43,6 +43,11 @@ localhost wire the fleet's delivered book equals its offered book — and
 an *exit* barrier holds sockets open until every result is collected.
 A hard ``timeout`` tears the fleet down rather than hanging.
 
+The launcher is the wire's CPU contract test, not an on-chip deployment
+path: a chip belongs to one process, so every child runs on the CPU
+(``JAX_PLATFORMS=cpu``, set outright) and each rank's result names the
+platform it ran on.
+
 Elastic fleets (`repro.fleet`): when the spec sets
 ``train.snapshot_dir``/``snapshot_every``, each child saves *its own*
 fleet snapshot slice every N local steps (params, optimizer, pool,
@@ -64,7 +69,6 @@ import contextlib
 import dataclasses
 import multiprocessing as mp
 import os
-import tempfile
 import time
 import traceback
 from collections import defaultdict
@@ -76,9 +80,11 @@ _DRAIN_ALL = 1 << 60  # poll step high enough to release every held frame
 def _child_run(spec_json: str, rank: int, conn, throttle_ms: float,
                die_at: Optional[int] = None, resume: bool = False,
                hard_timeout: float = 300.0) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # K processes cannot share one chip: every rank runs on the CPU
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
+    from repro.common.compile_cache import configure_compile_cache
     from repro.comm import SocketTransport
     from repro.exp import ExperimentSpec, make_algorithm
     from repro.exp.algorithm import Bindings
@@ -87,12 +93,9 @@ def _child_run(spec_json: str, rank: int, conn, throttle_ms: float,
     from repro.obs import trace
 
     # every rank compiles the same computations; one persistent cache
-    # (seeded by the launcher, or pre-warmed by an in-process run — see
-    # `launch_gossip`) turns K compilations into one compile + K-1 loads
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # (possibly pre-warmed by an in-process run) turns K compilations
+    # into one compile + K-1 loads
+    configure_compile_cache()
 
     t_start = time.perf_counter()
     spec = ExperimentSpec.from_json(spec_json).validate()
@@ -235,6 +238,7 @@ def _child_run(spec_json: str, rank: int, conn, throttle_ms: float,
     meter = trainer.meter
     conn.send(("result", rank, {
         "rank": rank,
+        "platform": jax.devices()[0].platform,
         "steps": spec.train.steps,
         "start_step": start_step,
         "wall_seconds": wall,
@@ -397,13 +401,6 @@ def launch_gossip(spec, timeout: float = 300.0,
     K = spec.num_clients
     ctx = mp.get_context("spawn")
     spec_json = spec.to_json()
-    # one persistent compilation cache for the whole fleet (children
-    # inherit the env through spawn): rank 0 compiles, everyone else
-    # loads — and later launches (or an in-process warm run, see
-    # benchmarks/socket_gossip.py) skip compilation entirely
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "repro_jit_cache"))
     conns, procs = [], []
     try:
         for rank in range(K):

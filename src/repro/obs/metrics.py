@@ -10,7 +10,8 @@ typed `ObsSnapshot`:
   * the tracer's phase attribution (self-time per span name, idle as the
     remainder) — where the wall-clock *went*;
   * `roofline/hlo_cost` analysis of the jitted distill update — what the
-    step *should* cost on the modeled hardware, and (when a trace is
+    step *should* cost on the running device (priced only where its
+    ``device_kind`` has published peaks), and (when a trace is
     available) the achieved-vs-attainable FLOP/s gap.
 
 ``ObsSnapshot.to_metrics()`` flattens everything under the ``obs/``
@@ -32,7 +33,7 @@ import dataclasses
 from collections import defaultdict
 from typing import Any, Dict, List, Optional
 
-from repro.roofline.analysis import V5E, HardwareSpec
+from repro.roofline.analysis import PEAKS
 from repro.roofline.hlo_cost import analyze_to_dict
 
 # span name -> report phase; names not listed fall back to their first
@@ -207,8 +208,7 @@ def flow_coverage(chrome_events: List[Dict[str, Any]]) -> Dict[str, float]:
 # -- roofline of the distill step --------------------------------------------
 
 
-def distill_step_cost(trainer, hw: HardwareSpec = V5E
-                      ) -> Dict[str, Dict[str, float]]:
+def distill_step_cost(trainer) -> Dict[str, Dict[str, float]]:
     """Loop-aware HLO cost of each architecture's jitted distill update.
 
     The runtime records the update's abstract arg shapes the first time
@@ -216,8 +216,14 @@ def distill_step_cost(trainer, hw: HardwareSpec = V5E
     (``trainer._distill_arg_shapes``); lowering the cached jitted
     function against those shapes yields the optimized HLO that
     `roofline/hlo_cost.analyze` prices. Attainable FLOP/s is the roofline
-    ``min(peak, bw · intensity)`` on ``hw``. Returns {} for trainers
-    that never distilled (or legacy baselines without the cache)."""
+    ``min(peak, bw · intensity)`` at the peaks of the running device's
+    ``device_kind`` (`roofline.analysis.PEAKS`); on a device without
+    published peaks it is left out, and only the HLO counts remain.
+    Returns {} for trainers that never distilled (or legacy baselines
+    without the cache)."""
+    import jax
+
+    hw = PEAKS.get(jax.devices()[0].device_kind)
     shapes = getattr(trainer, "_distill_arg_shapes", None) or {}
     cache = getattr(trainer, "_update_cache", None) or {}
     out: Dict[str, Dict[str, float]] = {}
@@ -231,8 +237,9 @@ def distill_step_cost(trainer, hw: HardwareSpec = V5E
         intensity = flops / nbytes if nbytes else 0.0
         out[name] = dict(cost)
         out[name]["intensity"] = intensity
-        out[name]["attainable_flops_per_s"] = min(
-            hw.peak_flops, hw.hbm_bw * intensity)
+        if hw is not None:
+            out[name]["attainable_flops_per_s"] = min(
+                hw.peak_flops, hw.hbm_bw * intensity)
     return out
 
 
@@ -254,9 +261,9 @@ def _achieved_flops(roofline: Dict[str, Dict[str, float]],
             row["distill_span_mean_s"] = mean_s
             row["achieved_flops_per_s"] = (
                 row["flops"] / mean_s if mean_s > 0 else 0.0)
-            att = row.get("attainable_flops_per_s", 0.0)
-            row["roofline_fraction"] = (
-                row["achieved_flops_per_s"] / att if att else 0.0)
+            att = row.get("attainable_flops_per_s")
+            if att:
+                row["roofline_fraction"] = row["achieved_flops_per_s"] / att
 
 
 # -- the snapshot ------------------------------------------------------------
@@ -297,7 +304,6 @@ class ObsSnapshot:
 
 
 def collect_obs(trainer=None, scheduler=None, tracer=None,
-                hw: HardwareSpec = V5E,
                 with_roofline: bool = False) -> ObsSnapshot:
     """Assemble the snapshot from whatever sources exist; every argument
     is optional and a missing source contributes an empty section.
@@ -325,7 +331,7 @@ def collect_obs(trainer=None, scheduler=None, tracer=None,
 
     roofline: Dict[str, Dict[str, float]] = {}
     if with_roofline and trainer is not None:
-        roofline = distill_step_cost(trainer, hw=hw)
+        roofline = distill_step_cost(trainer)
         _achieved_flops(roofline, tracer)
 
     return ObsSnapshot(comm=comm, gates=gates, freshness=freshness,
