@@ -75,6 +75,13 @@ from repro.models.zoo import ModelBundle
 from repro.optim.optimizers import Optimizer
 
 
+def _nbytes(tree) -> int:
+    """Bytes of a pytree of uploaded arrays: what crossed host→device
+    (after JAX's dtype canonicalisation, so float64 data counts as the
+    float32 it was sent as)."""
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
 @dataclasses.dataclass
 class RunConfig:
     steps: int = 1000
@@ -165,11 +172,6 @@ class DecentralizedTrainer:
         self._teacher_apply_cache: Dict[str, Callable] = {}
         self._update_cache: Dict[str, Callable] = {}
         self._supervised_cache: Dict[str, Callable] = {}
-        # abstract arg shapes of each bundle's distill update, captured on
-        # its first distillation step — enough to re-lower the jitted
-        # update for roofline costing (repro.obs.metrics.distill_step_cost)
-        # without holding any concrete arrays
-        self._distill_arg_shapes: Dict[str, Tuple] = {}
 
         self.exchange = exchange
         if exchange == "params":
@@ -412,12 +414,13 @@ class DecentralizedTrainer:
         if step % self.mhd_cfg.pool_update_every != 0:
             self._comm_tick(step)
             return
-        if self.exchange != "params":
-            self._publish_round(step)
-            self._resolve_pending(step)  # older rounds' pulls first
-        adj = self.graph_fn(step)
-        for c in self.local:
-            self._pull_client(c, step, adj)
+        with trace.span("pool/round", step=step):
+            if self.exchange != "params":
+                self._publish_round(step)
+                self._resolve_pending(step)  # older rounds' pulls first
+            adj = self.graph_fn(step)
+            for c in self.local:
+                self._pull_client(c, step, adj)
 
     def _comm_tick(self, step: int) -> None:
         """Between pool rounds: drain in-flight (latency) mail and complete
@@ -542,32 +545,36 @@ class DecentralizedTrainer:
         if not todo:
             return 0
         W = self.horizon
-        ids = np.stack([self.public.sample_ids(step + w) for w in range(W)])
-        batches = [{k: jnp.asarray(v)
-                    for k, v in self.public.sample(step + w).items()}
-                   for w in range(W)]
+        with trace.span("data/publish", step=step) as sp:
+            ids = np.stack([self.public.sample_ids(step + w)
+                            for w in range(W)])
+            batches = [{k: jnp.asarray(v)
+                        for k, v in self.public.sample(step + w).items()}
+                       for w in range(W)]
+            if trace.active():
+                sp.set(nbytes=_nbytes(batches))
         for c in todo:
-            t_fwd = trace.now()
-            apply_fn = self._teacher_apply(c.bundle)
-            frames = [apply_fn(c.params, b) for b in batches]
-            # stacked on device: the forward stays fully async here, and a
-            # codec with a device fast path (TopKCodec) packs wire arrays
-            # in-graph — only wire-dtype bytes ever reach the host
-            outs = {key: jnp.stack([f[key] for f in frames])
-                    .astype(jnp.float32)
-                    for key in ("embedding", "logits", "aux_logits")}
-            trace.complete("publish/forward", t_fwd, client=c.client_id,
-                           step=step, window=W)
-            t_enc = trace.now()
-            try:
-                payload = self.codec.encode(c.client_id, step, step, ids,
-                                            outs)
-            except NonFiniteError:
-                if self.meter is not None:
-                    self.meter.rejected_publishes += 1
-                continue
-            trace.complete("publish/encode", t_enc, client=c.client_id,
-                           step=step, nbytes=len(payload))
+            with trace.span("publish/forward", client=c.client_id,
+                            step=step, window=W):
+                apply_fn = self._teacher_apply(c.bundle)
+                frames = [apply_fn(c.params, b) for b in batches]
+                # stacked on device: the forward stays fully async here,
+                # and a codec with a device fast path (TopKCodec) packs
+                # wire arrays in-graph — only wire-dtype bytes ever reach
+                # the host
+                outs = {key: jnp.stack([f[key] for f in frames])
+                        .astype(jnp.float32)
+                        for key in ("embedding", "logits", "aux_logits")}
+            with trace.span("publish/encode", client=c.client_id,
+                            step=step) as sp:
+                try:
+                    payload = self.codec.encode(c.client_id, step, step,
+                                                ids, outs)
+                except NonFiniteError:
+                    if self.meter is not None:
+                        self.meter.rejected_publishes += 1
+                    continue
+                sp.set(nbytes=len(payload))
             self.bus.publish(c.client_id, payload, step)
         return len(todo)
 
@@ -595,34 +602,44 @@ class DecentralizedTrainer:
         predictions in prediction modes. Returns ``(teachers, skipped)``;
         teachers is None when nothing survived the gate (supervised
         fallback, never an error)."""
-        entries = client.pool.sample(self.mhd_cfg.delta)
-        sampled = len(entries)
-        if self.exchange != "params":
-            entries = client.pool.usable(entries, step)
-        ms = self.run_cfg.max_staleness
-        if ms is not None:
-            entries = [e for e in entries if step - e.step <= ms]
-        skipped = sampled - len(entries)
-        if skipped:
-            trace.instant("runtime/gate_skip", client=client.client_id,
-                          step=step, fresh=len(entries), skipped=skipped)
-        if self.meter is not None and sampled:
-            self.meter.record_gate(client.client_id, len(entries), skipped)
-        if not entries:
-            return None, skipped
-        # pad to Δ by cycling over the originally sampled entries
-        entries = [entries[i % len(entries)]
-                   for i in range(self.mhd_cfg.delta)]
-        outs = []
-        for e in entries:
-            if self.exchange == "params":
-                teacher_bundle = self.clients[e.client_id].bundle
-                outs.append(self._teacher_apply(teacher_bundle)(
-                    e.params, public_batch))
-            else:
-                outs.append({k: jnp.asarray(v)
-                             for k, v in e.params.frame(step).items()})
-        return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *outs), skipped
+        with trace.span("teacher/stack", client=client.client_id,
+                        step=step) as sp:
+            entries = client.pool.sample(self.mhd_cfg.delta)
+            sampled = len(entries)
+            if self.exchange != "params":
+                entries = client.pool.usable(entries, step)
+            ms = self.run_cfg.max_staleness
+            if ms is not None:
+                entries = [e for e in entries if step - e.step <= ms]
+            skipped = sampled - len(entries)
+            if skipped:
+                trace.instant("runtime/gate_skip", client=client.client_id,
+                              step=step, fresh=len(entries), skipped=skipped)
+            if self.meter is not None and sampled:
+                self.meter.record_gate(client.client_id, len(entries),
+                                       skipped)
+            if not entries:
+                sp.set(nbytes=0)
+                return None, skipped
+            # pad to Δ by cycling over the originally sampled entries
+            entries = [entries[i % len(entries)]
+                       for i in range(self.mhd_cfg.delta)]
+            outs = []
+            for e in entries:
+                if self.exchange == "params":
+                    teacher_bundle = self.clients[e.client_id].bundle
+                    outs.append(self._teacher_apply(teacher_bundle)(
+                        e.params, public_batch))
+                else:
+                    outs.append({k: jnp.asarray(v)
+                                 for k, v in e.params.frame(step).items()})
+            teachers = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *outs)
+            if trace.active():
+                # prediction modes upload the decoded frames; legacy mode
+                # scores its teachers on the device and uploads nothing
+                sp.set(nbytes=0 if self.exchange == "params"
+                       else _nbytes(teachers))
+            return teachers, skipped
 
     # -- training loop -----------------------------------------------------
 
@@ -647,36 +664,36 @@ class DecentralizedTrainer:
         t_step = trace.now()
         if self.exchange != "params":
             self.bus.advance(c.client_id, t)
-        private_np = c.private_iter.next()
-        private_batch = {k: jnp.asarray(v) for k, v in private_np.items()}
+        with trace.span("data/private", client=c.client_id, step=t) as sp:
+            private_np = c.private_iter.next()
+            private_batch = {k: jnp.asarray(v)
+                             for k, v in private_np.items()}
+            if trace.active():
+                sp.set(nbytes=_nbytes(private_batch))
         teachers, skipped = self._stack_teachers(c, public_batch, t)
-        rng = jax.random.PRNGKey((t << 10) + c.client_id)
-        step_arg = jnp.asarray(opt_step)
-        if teachers is None:
-            t_up = trace.now()
-            update = self._supervised_update(c.bundle)
-            c.params, c.opt_state, metrics = update(
-                c.params, c.opt_state, private_batch, step_arg)
-        else:
-            if c.bundle.name not in self._distill_arg_shapes:
-                self._distill_arg_shapes[c.bundle.name] = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(
-                        jnp.shape(x), jnp.result_type(x)),
-                    (c.params, c.opt_state, private_batch, public_batch,
-                     teachers, step_arg, rng))
-            t_up = trace.now()
-            update = self._client_update(c.bundle)
-            c.params, c.opt_state, metrics = update(
-                c.params, c.opt_state, private_batch, public_batch,
-                teachers, step_arg, rng)
+        t_up = trace.now()
+        with trace.span("runtime/dispatch", client=c.client_id, step=t,
+                        distill=teachers is not None):
+            rng = jax.random.PRNGKey((t << 10) + c.client_id)
+            step_arg = jnp.asarray(opt_step)
+            if teachers is None:
+                update = self._supervised_update(c.bundle)
+                c.params, c.opt_state, metrics = update(
+                    c.params, c.opt_state, private_batch, step_arg)
+            else:
+                update = self._client_update(c.bundle)
+                c.params, c.opt_state, metrics = update(
+                    c.params, c.opt_state, private_batch, public_batch,
+                    teachers, step_arg, rng)
 
         def resolve() -> Dict[str, float]:
             # the float() conversions block on the device computation, so
             # the retro-emitted update span covers dispatch → completion;
             # overlapped comm spans emitted in between nest inside it and
             # the tracer's self-time sweep subtracts them
-            out = {f"c{c.client_id}/{k}": float(v)
-                   for k, v in metrics.items()}
+            with trace.span("runtime/wait", client=c.client_id, step=t):
+                out = {f"c{c.client_id}/{k}": float(v)
+                       for k, v in metrics.items()}
             trace.complete(
                 "runtime/supervised" if teachers is None
                 else "runtime/distill",
@@ -695,18 +712,23 @@ class DecentralizedTrainer:
         return resolve if defer else resolve()
 
     def step(self, t: int) -> Dict[str, float]:
-        public_np = self.public.sample(t)
-        public_batch = {k: jnp.asarray(v) for k, v in public_np.items()}
-        # dispatch every client's update, run the communication phase
-        # while the device computes, then block on the metrics. Resolved
-        # LIFO so the retro-emitted per-client trace spans nest instead
-        # of overlapping (the tracer assumes single-threaded nesting).
-        pending = [self.step_client(c, public_batch, t, defer=True)
-                   for c in self.local]
-        self._maybe_update_pools(t + 1)
-        all_metrics: Dict[str, float] = {}
-        for resolve in reversed(pending):
-            all_metrics.update(resolve())
+        with trace.span("runtime/fleet_step", step=t):
+            with trace.span("data/public", step=t) as sp:
+                public_batch = {k: jnp.asarray(v)
+                                for k, v in self.public.sample(t).items()}
+                if trace.active():
+                    sp.set(nbytes=_nbytes(public_batch))
+            # dispatch every client's update, run the communication phase
+            # while the device computes, then block on the metrics.
+            # Resolved LIFO so the retro-emitted per-client trace spans
+            # nest instead of overlapping (the tracer assumes
+            # single-threaded nesting).
+            pending = [self.step_client(c, public_batch, t, defer=True)
+                       for c in self.local]
+            self._maybe_update_pools(t + 1)
+            all_metrics: Dict[str, float] = {}
+            for resolve in reversed(pending):
+                all_metrics.update(resolve())
         return all_metrics
 
     def train(self, eval_arrays: Optional[Dict[str, np.ndarray]] = None,

@@ -315,7 +315,7 @@ class Experiment:
             obs = collect_obs(
                 trainer=getattr(algo, "trainer", None),
                 scheduler=getattr(algo, "scheduler", None),
-                tracer=tracer, with_roofline=True)
+                tracer=tracer)
             metrics.update(obs.to_metrics())
         return ExperimentResult(
             spec=spec, metrics=metrics, history=history,

@@ -1,6 +1,6 @@
-"""Unified observability snapshot: comm books + freshness + trace + roofline.
+"""Unified observability snapshot: comm books + freshness + trace.
 
-`collect_obs` folds four previously disjoint telemetry sources into one
+`collect_obs` folds three previously disjoint telemetry sources into one
 typed `ObsSnapshot`:
 
   * the `CommMeter` books (offered / delivered / tombstoned bytes, gate
@@ -8,11 +8,7 @@ typed `ObsSnapshot`:
   * the scheduler's freshness report (per-client mailbox vs its own
     clock) — what the fleet *sees*;
   * the tracer's phase attribution (self-time per span name, idle as the
-    remainder) — where the wall-clock *went*;
-  * `roofline/hlo_cost` analysis of the jitted distill update — what the
-    step *should* cost on the running device (priced only where its
-    ``device_kind`` has published peaks), and (when a trace is
-    available) the achieved-vs-attainable FLOP/s gap.
+    remainder) — where the wall-clock *went*.
 
 ``ObsSnapshot.to_metrics()`` flattens everything under the ``obs/``
 namespace, which `Experiment.run()` merges into the result metrics when
@@ -31,16 +27,15 @@ from __future__ import annotations
 
 import dataclasses
 from collections import defaultdict
-from typing import Any, Dict, List, Optional
-
-from repro.roofline.analysis import PEAKS
-from repro.roofline.hlo_cost import analyze_to_dict
+from typing import Any, Dict, List
 
 # span name -> report phase; names not listed fall back to their first
 # path segment ("sched/tick" -> "sched"). The report's headline phases:
 PHASE_OF = {
     "runtime/distill": "distill",
     "runtime/supervised": "distill",
+    "runtime/dispatch": "distill",
+    "runtime/wait": "distill",
     "publish/forward": "encode",
     "publish/encode": "encode",
     "wire/serialize": "encode",
@@ -56,6 +51,12 @@ PHASE_OF = {
     "gossip/setup": "setup",
     "runtime/step": "step_other",
     "runtime/resolve": "step_other",
+    "runtime/fleet_step": "step_other",
+    "data/public": "step_other",
+    "data/private": "step_other",
+    "data/publish": "step_other",
+    "teacher/stack": "step_other",
+    "pool/round": "step_other",
     "sched/tick": "step_other",
     # scoreboard stalls: pace/idle waits and run-ahead backpressure
     "sched/wait": "sched_stall",
@@ -205,67 +206,6 @@ def flow_coverage(chrome_events: List[Dict[str, Any]]) -> Dict[str, float]:
             "flow_pairs": float(len(starts & ends))}
 
 
-# -- roofline of the distill step --------------------------------------------
-
-
-def distill_step_cost(trainer) -> Dict[str, Dict[str, float]]:
-    """Loop-aware HLO cost of each architecture's jitted distill update.
-
-    The runtime records the update's abstract arg shapes the first time
-    each bundle takes a distillation step
-    (``trainer._distill_arg_shapes``); lowering the cached jitted
-    function against those shapes yields the optimized HLO that
-    `roofline/hlo_cost.analyze` prices. Attainable FLOP/s is the roofline
-    ``min(peak, bw · intensity)`` at the peaks of the running device's
-    ``device_kind`` (`roofline.analysis.PEAKS`); on a device without
-    published peaks it is left out, and only the HLO counts remain.
-    Returns {} for trainers that never distilled (or legacy baselines
-    without the cache)."""
-    import jax
-
-    hw = PEAKS.get(jax.devices()[0].device_kind)
-    shapes = getattr(trainer, "_distill_arg_shapes", None) or {}
-    cache = getattr(trainer, "_update_cache", None) or {}
-    out: Dict[str, Dict[str, float]] = {}
-    for name, args in shapes.items():
-        fn = cache.get(name)
-        if fn is None:
-            continue
-        hlo = fn.lower(*args).compile().as_text()
-        cost = analyze_to_dict(hlo)
-        flops, nbytes = cost["flops"], cost["bytes"]
-        intensity = flops / nbytes if nbytes else 0.0
-        out[name] = dict(cost)
-        out[name]["intensity"] = intensity
-        if hw is not None:
-            out[name]["attainable_flops_per_s"] = min(
-                hw.peak_flops, hw.hbm_bw * intensity)
-    return out
-
-
-def _achieved_flops(roofline: Dict[str, Dict[str, float]],
-                    tracer) -> None:
-    """Annotate each bundle's roofline row with the achieved FLOP/s from
-    its traced ``runtime/distill`` span durations (in place)."""
-    if tracer is None:
-        return
-    durs: Dict[str, List[float]] = defaultdict(list)
-    for ev in tracer.events():
-        if ev["ph"] == "X" and ev["name"] == "runtime/distill":
-            b = ev.get("args", {}).get("bundle")
-            if b is not None:
-                durs[b].append(ev["dur"])
-    for name, row in roofline.items():
-        if durs.get(name):
-            mean_s = sum(durs[name]) / len(durs[name])
-            row["distill_span_mean_s"] = mean_s
-            row["achieved_flops_per_s"] = (
-                row["flops"] / mean_s if mean_s > 0 else 0.0)
-            att = row.get("attainable_flops_per_s")
-            if att:
-                row["roofline_fraction"] = row["achieved_flops_per_s"] / att
-
-
 # -- the snapshot ------------------------------------------------------------
 
 
@@ -278,7 +218,6 @@ class ObsSnapshot:
     freshness: Dict[int, Dict[str, float]]
     tracer_stats: Dict[str, float]
     phases: Dict[int, Dict[str, float]]
-    roofline: Dict[str, Dict[str, float]]
 
     def to_metrics(self) -> Dict[str, float]:
         """Flatten under the ``obs/`` namespace for the unified metric
@@ -297,18 +236,12 @@ class ObsSnapshot:
         for pid, row in self.phases.items():
             for k, v in row.items():
                 out[f"obs/phase/r{pid}/{k}"] = float(v)
-        for name, row in self.roofline.items():
-            for k, v in row.items():
-                out[f"obs/roofline/{name}/{k}"] = float(v)
         return out
 
 
-def collect_obs(trainer=None, scheduler=None, tracer=None,
-                with_roofline: bool = False) -> ObsSnapshot:
+def collect_obs(trainer=None, scheduler=None, tracer=None) -> ObsSnapshot:
     """Assemble the snapshot from whatever sources exist; every argument
-    is optional and a missing source contributes an empty section.
-    ``with_roofline`` gates the HLO lowering (an extra compile of each
-    distill update — cheap but not free, so opt-in)."""
+    is optional and a missing source contributes an empty section."""
     comm: Dict[str, float] = {}
     gates: Dict[int, Dict[str, float]] = {}
     meter = getattr(trainer, "meter", None)
@@ -326,14 +259,13 @@ def collect_obs(trainer=None, scheduler=None, tracer=None,
         from repro.obs.export import to_chrome_events
 
         tracer_stats = tracer.stats()
+        events = tracer.events()
+        # rebased at the earliest event: absolute perf_counter µs (~1e10)
+        # would round µs spans apart and the phases would no longer sum
+        # to the wall
+        base = min((e["ts"] for e in events), default=0.0)
         phases = phase_attribution(
-            to_chrome_events(tracer.events(), pid=tracer.rank))
-
-    roofline: Dict[str, Dict[str, float]] = {}
-    if with_roofline and trainer is not None:
-        roofline = distill_step_cost(trainer)
-        _achieved_flops(roofline, tracer)
+            to_chrome_events(events, pid=tracer.rank, base_s=base))
 
     return ObsSnapshot(comm=comm, gates=gates, freshness=freshness,
-                       tracer_stats=tracer_stats, phases=phases,
-                       roofline=roofline)
+                       tracer_stats=tracer_stats, phases=phases)

@@ -17,6 +17,12 @@ Design constraints, in order:
      with a per-process arbitrary epoch. Cross-process alignment is the
      merge step's job (`export.merge_traces`), using rendezvous-handshake
      *anchors* recorded here via ``set_anchor``.
+  4. **Context spans reach the profiler.** While enabled, every
+     ``span(...)`` also opens a ``jax.profiler.TraceAnnotation`` of the
+     same name and args, so under a `jax.profiler` session it lands on
+     the profiler's clock beside the device ops (a no-op costing well
+     under 1 µs without a session). Retro-emitted ``complete`` spans
+     stay in the ring buffer only: they did not exist while the work ran.
 
 Event kinds map 1:1 onto Chrome trace-event phases (`export.py`):
 ``"X"`` complete span, ``"i"`` instant, ``"C"`` counter, ``"s"``/``"f"``
@@ -29,8 +35,9 @@ Usage::
     from repro.obs import trace
 
     trace.enable(rank=3)                      # or leave disabled (no-op)
-    with trace.span("encode", client=1, nbytes=n):
+    with trace.span("encode", client=1) as sp:
         ...
+        sp.set(nbytes=n)                      # args known only inside
     trace.instant("gate_skip", client=1)
     trace.counter("mailbox", 4, client=1)
 """
@@ -68,28 +75,40 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self._name = name
-        self._args = args
+        self._args = args if args is not None else {}
 
     def __enter__(self) -> "_Span":
+        self._annotation = self._tracer._annotation(self._name,
+                                                    **self._args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **args) -> None:
+        """Add args learned inside the span (an upload's byte count)."""
+        self._args.update(args)
+        self._annotation.set_metadata(**args)
+
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         self._tracer._emit({"ph": "X", "name": self._name, "ts": self._t0,
                             "dur": t1 - self._t0, "tid": _tid(),
-                            "args": self._args or {}})
+                            "args": self._args})
         return False
 
 
@@ -104,6 +123,11 @@ class Tracer:
                  process_name: Optional[str] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
+        # imported here so that reading traces (scripts/trace_report.py)
+        # does not import JAX
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.capacity = int(capacity)
         self.rank = int(rank)
         self.process_name = process_name or f"rank {rank}"

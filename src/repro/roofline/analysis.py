@@ -28,12 +28,6 @@ class HardwareSpec:
 
 V5E = HardwareSpec()
 
-# Published per-chip peaks keyed by `jax.Device.device_kind` (source:
-# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
-# at 819 GB/s). A device not listed has no peaks: what would be priced
-# against them reads as not measured, never as a default device's.
-PEAKS: Dict[str, HardwareSpec] = {"TPU v5 lite": V5E}
-
 
 @dataclasses.dataclass
 class RooflineReport:

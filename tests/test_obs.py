@@ -1,6 +1,8 @@
 """Tests for `repro.obs`: the span tracer, Chrome-trace export and
 cross-process merge, phase attribution, and the experiment wiring."""
+import glob
 import json
+import os
 
 import pytest
 
@@ -19,6 +21,7 @@ from repro.obs.metrics import (
     stall_spans,
 )
 from repro.obs.tracer import Tracer, flow_id
+from test_comm import _make_trainer
 
 
 @pytest.fixture(autouse=True)
@@ -262,11 +265,25 @@ def test_collect_obs_folds_meter_and_tracer():
             if k.startswith("obs/phase/r0/") and not k.endswith("/wall")))
 
 
+def test_collect_obs_phases_sum_to_wall_on_a_late_clock():
+    """Microsecond spans recorded ~10 h into a host's uptime: the phases
+    still sum to the wall, because the export is rebased at the earliest
+    event (absolute perf_counter µs would round them apart)."""
+    tracer = Tracer()
+    tracer._emit({"ph": "X", "name": "runtime/distill",
+                  "ts": 35000.69583286677, "dur": 7e-7, "tid": 0,
+                  "args": {}})
+    row = collect_obs(tracer=tracer).phases[0]
+    assert row["wall"] == pytest.approx(7e-7)
+    assert row["wall"] == pytest.approx(
+        sum(v for k, v in row.items() if k != "wall"))
+
+
 @pytest.mark.slow
 def test_experiment_trace_dir_writes_trace_and_obs_metrics(tmp_path):
     """TrainSpec.trace_dir turns the runner's tracing on: a Chrome trace
-    lands in the dir and the result metrics gain the obs/ namespace,
-    roofline rows included."""
+    with the runtime's spans lands in the dir and the result metrics gain
+    the obs/ namespace."""
     from repro.exp import (DataSpec, Experiment, ExperimentSpec,
                            OptimizerSpec, PartitionSpec, TrainSpec)
 
@@ -288,10 +305,158 @@ def test_experiment_trace_dir_writes_trace_and_obs_metrics(tmp_path):
     assert "runtime/distill" in names and "runtime/step" in names
     assert res.metrics["obs/trace/dropped"] == 0.0
     assert res.metrics["obs/phase/r0/distill"] > 0.0
-    roofline = {k: v for k, v in res.metrics.items()
-                if k.startswith("obs/roofline/")}
-    assert any(k.endswith("/flops") for k in roofline)
-    assert any(k.endswith("/achieved_flops_per_s") for k in roofline)
+    # the host work between launches is in the written trace too
+    assert set(HOST_SPANS) - {"data/publish", "pool/round"} <= names
+    assert not any(k.startswith("obs/roofline/") for k in res.metrics)
     # tracing is opt-in: a plain run leaves no obs/ keys behind
     res2 = Experiment(tiny_spec(steps=2)).run()
     assert not any(k.startswith("obs/") for k in res2.metrics)
+
+
+# ---------------------------------------------------------------------------
+# the runtime's host spans, in the tracer and in the profiler's trace
+# ---------------------------------------------------------------------------
+
+# every host boundary between device launches in `DecentralizedTrainer.step`
+HOST_SPANS = ("runtime/fleet_step", "data/public", "data/private",
+              "teacher/stack", "runtime/dispatch", "runtime/wait",
+              "pool/round", "data/publish")
+
+
+def _profiled_host_events(directory):
+    """(name, stats) of every event on the profiler's Python threads."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines if line.name.startswith("python")
+            for e in line.events]
+
+
+class _Fleet:
+    """Two tiny ResNet clients on the top-k wire, a pool round every 2
+    steps, compiled by two untraced steps. ``step()`` runs the next fleet
+    step; odd steps end in a pool round."""
+
+    def __init__(self):
+        from repro.comm import CommConfig
+
+        self.trainer = _make_trainer("prediction_topk", K=2, labels=4,
+                                     steps=100, comm=CommConfig(topk=2))
+        self.t = 0
+        self.step()
+        self.step()
+
+    def step(self):
+        self.trainer.step(self.t)
+        self.t += 1
+
+
+@pytest.fixture(scope="module")
+def fleet():
+    return _Fleet()
+
+
+def test_tracer_writes_context_spans_into_a_profiler_session(tmp_path):
+    """A context span lands on the profiler's Python thread with its args,
+    those set inside it included; a retro-emitted span stays in the
+    tracer's buffer only."""
+    import jax
+
+    tracer = trace.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("data/private", client=1, step=7) as sp:
+            sp.set(nbytes=96)
+        trace.complete("runtime/distill", trace.now(), client=1)
+    finally:
+        jax.profiler.stop_trace()
+        trace.disable()
+    events = _profiled_host_events(str(tmp_path))
+    assert ("data/private", {"client": 1, "step": 7, "nbytes": 96}) in events
+    assert "runtime/distill" not in {n for n, _ in events}
+    assert [e["name"] for e in tracer.events()] == ["data/private",
+                                                    "runtime/distill"]
+    assert tracer.events()[0]["args"] == {"client": 1, "step": 7,
+                                          "nbytes": 96}
+
+
+def test_fleet_step_emits_host_spans_with_args_inside_the_step(fleet):
+    if fleet.t % 2:
+        fleet.step()  # untraced: the traced pair starts on an even step
+    tracer = trace.enable()
+    t0 = fleet.t
+    fleet.step()
+    fleet.step()  # ends in a pool round
+    trace.disable()
+    spans = [e for e in tracer.events() if e["ph"] == "X"]
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    assert set(HOST_SPANS) <= set(by_name)
+    steps = by_name["runtime/fleet_step"]
+    assert [e["args"] for e in steps] == [{"step": t0}, {"step": t0 + 1}]
+    for name in HOST_SPANS[1:]:
+        for e in by_name[name]:
+            assert any(o["ts"] <= e["ts"] and
+                       e["ts"] + e["dur"] <= o["ts"] + o["dur"]
+                       for o in steps), name
+    image = 8 * 8 * 3 * 4  # a float32 8x8 RGB image
+    for e in by_name["data/private"]:
+        assert e["args"]["nbytes"] == 8 * (image + 4)  # and int32 labels
+    for e in by_name["data/public"]:
+        assert e["args"]["nbytes"] == 16 * image  # unlabelled
+    assert sorted((e["args"]["client"], e["args"]["step"])
+                  for e in by_name["data/private"]) == \
+        [(0, t0), (0, t0 + 1), (1, t0), (1, t0 + 1)]
+    for e in by_name["teacher/stack"]:
+        # a distilling step uploads its teacher frame, a fallback nothing
+        assert (e["args"]["nbytes"] > 0) == any(
+            d["args"]["distill"] for d in by_name["runtime/dispatch"]
+            if (d["args"]["client"], d["args"]["step"]) ==
+            (e["args"]["client"], e["args"]["step"]))
+    assert {(e["args"]["client"], e["args"]["step"])
+            for e in by_name["runtime/wait"]} == \
+        {(c, t) for c in (0, 1) for t in (t0, t0 + 1)}
+    pool, = by_name["pool/round"]
+    assert pool["args"] == {"step": t0 + 2}
+    publish, = by_name["data/publish"]
+    assert publish["args"] == {"step": t0 + 2, "nbytes": 2 * 16 * image}
+
+
+def test_traced_fleet_step_lands_on_the_profilers_python_thread(
+        fleet, tmp_path):
+    import jax
+
+    trace.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fleet.step()
+    finally:
+        jax.profiler.stop_trace()
+        trace.disable()
+    events = _profiled_host_events(str(tmp_path))
+    names = {n for n, _ in events}
+    assert {"data/private", "teacher/stack", "runtime/dispatch",
+            "runtime/wait", "runtime/fleet_step"} <= names
+    assert all(stats["nbytes"] > 0 for n, stats in events
+               if n == "data/private")
+
+
+def test_fleet_step_with_tracing_off_records_nothing(fleet, tmp_path):
+    import jax
+
+    tracer = trace.enable()
+    trace.disable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fleet.step()
+        fleet.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert tracer.events() == [] and tracer.emitted == 0
+    names = {n for n, _ in _profiled_host_events(str(tmp_path))}
+    assert not names & set(HOST_SPANS)
