@@ -70,7 +70,15 @@ from repro.core.evaluation import (
 )
 from repro.core.graph import Adjacency, as_graph_fn, validate_adjacency
 from repro.core.mhd import MHDConfig, mhd_total_loss
-from repro.data.pipeline import BatchIterator, PublicPool, client_stream_seed
+from repro.data.pipeline import (
+    INDEX_DTYPE,
+    BatchIterator,
+    DeviceData,
+    PublicPool,
+    client_stream_seed,
+    dataset_nbytes,
+    fits_on_device,
+)
 from repro.models.zoo import ModelBundle
 from repro.optim.optimizers import Optimizer
 
@@ -80,6 +88,15 @@ def _nbytes(tree) -> int:
     (after JAX's dtype canonicalisation, so float64 data counts as the
     float32 it was sent as)."""
     return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
+def _batch_nbytes(batches: Sequence[Dict], resident: bool) -> int:
+    """What batches sent host→device: their rows' indices where they were
+    gathered from the dataset's device copy, else the rows."""
+    if not resident:
+        return _nbytes(batches)
+    rows = sum(len(next(iter(b.values()))) for b in batches)
+    return rows * np.dtype(INDEX_DTYPE).itemsize
 
 
 @dataclasses.dataclass
@@ -167,8 +184,6 @@ class DecentralizedTrainer:
         self.optimizer = optimizer
         self.num_labels = num_labels
         self.rng = np.random.default_rng(run_cfg.seed)
-        self.public = PublicPool(arrays, public_indices,
-                                 run_cfg.public_batch_size, seed=run_cfg.seed)
         self._teacher_apply_cache: Dict[str, Callable] = {}
         self._update_cache: Dict[str, Callable] = {}
         self._supervised_cache: Dict[str, Callable] = {}
@@ -204,7 +219,6 @@ class DecentralizedTrainer:
 
         self.init_scheme = init_scheme
         self.membership = membership
-        self._arrays = arrays
         self._client_indices = list(client_indices)
         # which clients this trainer actually ran model init for — the
         # per_client scheme's O(K) startup claim is asserted on this
@@ -232,10 +246,7 @@ class DecentralizedTrainer:
                 pool=pool_cls(mhd_cfg.pool_size,
                               mhd_cfg.pool_update_every,
                               seed=run_cfg.seed + 101 * i),
-                private_iter=BatchIterator(arrays, client_indices[i],
-                                           run_cfg.batch_size,
-                                           seed=client_stream_seed(
-                                               run_cfg.seed, i)),
+                private_iter=None,
                 label_hist=label_histogram(arrays["labels"],
                                            client_indices[i], num_labels),
             ))
@@ -247,7 +258,27 @@ class DecentralizedTrainer:
             self._dead = {i for i in range(len(bundles)) if i not in alive0}
         self.local = [self.clients[i] for i in self.local_ids
                       if i not in self._dead]
+        # one device copy of the dataset serves every client's iterator and
+        # the public pool where it takes a small share of the memory left
+        # once the clients' state is placed; a larger one stays on the host
+        # and batches are gathered there and uploaded
+        jax.block_until_ready([(c.params, c.opt_state)
+                               for c in self.clients])
+        nbytes = dataset_nbytes(arrays)
+        on_device = fits_on_device(nbytes)
+        trace.instant("data/resident", nbytes=nbytes, on_device=on_device)
+        self._data = DeviceData.put(arrays) if on_device else arrays
+        self.public = PublicPool(self._data, public_indices,
+                                 run_cfg.public_batch_size, seed=run_cfg.seed)
+        for c in self.clients:
+            c.private_iter = self._private_iter(c.client_id)
         self._seed_pools(step=0)
+
+    def _private_iter(self, cid: int) -> BatchIterator:
+        """Client ``cid``'s private stream from its start."""
+        return BatchIterator(self._data, self._client_indices[cid],
+                             self.run_cfg.batch_size,
+                             seed=client_stream_seed(self.run_cfg.seed, cid))
 
     # -- jitted function caches ------------------------------------------
 
@@ -402,9 +433,7 @@ class DecentralizedTrainer:
         sub = jax.random.fold_in(jax.random.PRNGKey(self.run_cfg.seed), cid)
         c.params = c.bundle.init(sub)
         c.opt_state = self.optimizer.init(c.params)
-        c.private_iter = BatchIterator(
-            self._arrays, self._client_indices[cid], self.run_cfg.batch_size,
-            seed=client_stream_seed(self.run_cfg.seed, cid))
+        c.private_iter = self._private_iter(cid)
         c.pool = type(c.pool)(self.mhd_cfg.pool_size,
                               self.mhd_cfg.pool_update_every,
                               seed=self.run_cfg.seed + 101 * cid)
@@ -545,14 +574,15 @@ class DecentralizedTrainer:
         if not todo:
             return 0
         W = self.horizon
-        with trace.span("data/publish", step=step) as sp:
+        with trace.span("data/publish", step=step,
+                        resident=self.public.resident) as sp:
             ids = np.stack([self.public.sample_ids(step + w)
                             for w in range(W)])
             batches = [{k: jnp.asarray(v)
                         for k, v in self.public.sample(step + w).items()}
                        for w in range(W)]
             if trace.active():
-                sp.set(nbytes=_nbytes(batches))
+                sp.set(nbytes=_batch_nbytes(batches, self.public.resident))
         for c in todo:
             with trace.span("publish/forward", client=c.client_id,
                             step=step, window=W):
@@ -664,12 +694,14 @@ class DecentralizedTrainer:
         t_step = trace.now()
         if self.exchange != "params":
             self.bus.advance(c.client_id, t)
-        with trace.span("data/private", client=c.client_id, step=t) as sp:
+        with trace.span("data/private", client=c.client_id, step=t,
+                        resident=c.private_iter.resident) as sp:
             private_np = c.private_iter.next()
             private_batch = {k: jnp.asarray(v)
                              for k, v in private_np.items()}
             if trace.active():
-                sp.set(nbytes=_nbytes(private_batch))
+                sp.set(nbytes=_batch_nbytes([private_batch],
+                                            c.private_iter.resident))
         teachers, skipped = self._stack_teachers(c, public_batch, t)
         t_up = trace.now()
         with trace.span("runtime/dispatch", client=c.client_id, step=t,
@@ -713,11 +745,13 @@ class DecentralizedTrainer:
 
     def step(self, t: int) -> Dict[str, float]:
         with trace.span("runtime/fleet_step", step=t):
-            with trace.span("data/public", step=t) as sp:
+            with trace.span("data/public", step=t,
+                            resident=self.public.resident) as sp:
                 public_batch = {k: jnp.asarray(v)
                                 for k, v in self.public.sample(t).items()}
                 if trace.active():
-                    sp.set(nbytes=_nbytes(public_batch))
+                    sp.set(nbytes=_batch_nbytes([public_batch],
+                                                self.public.resident))
             # dispatch every client's update, run the communication phase
             # while the device computes, then block on the metrics.
             # Resolved LIFO so the retro-emitted per-client trace spans

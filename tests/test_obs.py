@@ -404,11 +404,13 @@ def test_fleet_step_emits_host_spans_with_args_inside_the_step(fleet):
             assert any(o["ts"] <= e["ts"] and
                        e["ts"] + e["dur"] <= o["ts"] + o["dur"]
                        for o in steps), name
-    image = 8 * 8 * 3 * 4  # a float32 8x8 RGB image
+    # the CPU reports no memory limit, so the dataset is on the device and
+    # a batch sends only its rows' int32 indices
+    index = 4
     for e in by_name["data/private"]:
-        assert e["args"]["nbytes"] == 8 * (image + 4)  # and int32 labels
+        assert e["args"]["nbytes"] == 8 * index
     for e in by_name["data/public"]:
-        assert e["args"]["nbytes"] == 16 * image  # unlabelled
+        assert e["args"]["nbytes"] == 16 * index
     assert sorted((e["args"]["client"], e["args"]["step"])
                   for e in by_name["data/private"]) == \
         [(0, t0), (0, t0 + 1), (1, t0), (1, t0 + 1)]
@@ -424,7 +426,10 @@ def test_fleet_step_emits_host_spans_with_args_inside_the_step(fleet):
     pool, = by_name["pool/round"]
     assert pool["args"] == {"step": t0 + 2}
     publish, = by_name["data/publish"]
-    assert publish["args"] == {"step": t0 + 2, "nbytes": 2 * 16 * image}
+    assert publish["args"] == {"step": t0 + 2, "resident": True,
+                               "nbytes": 2 * 16 * index}
+    assert all(e["args"]["resident"] for name in
+               ("data/private", "data/public") for e in by_name[name])
 
 
 def test_traced_fleet_step_lands_on_the_profilers_python_thread(

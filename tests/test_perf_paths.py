@@ -162,3 +162,146 @@ def test_nested_remat_same_loss():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=1e-3, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the fleet's dataset on the device (data/pipeline.DeviceData)
+# ---------------------------------------------------------------------------
+
+STEPS = 5  # a pool round every 2 steps: two publish rounds
+
+
+def _run_fleet():
+    """A 2-client top-k fleet run for `STEPS` steps with the tracer on.
+    Every client's ``private_iter.next`` is wrapped as the benchmark's
+    ``checked_steps`` wraps it, recording each private batch."""
+    from test_comm import _make_trainer
+
+    from repro.comm import CommConfig
+    from repro.obs import tracer as trace
+
+    tracer = trace.enable()
+    try:
+        tr = _make_trainer("prediction_topk", K=2, labels=4, steps=STEPS,
+                           comm=CommConfig(topk=2))
+        recorded = {c.client_id: [] for c in tr.clients}
+        for c in tr.clients:
+            def record(nxt=c.private_iter.next, i=c.client_id):
+                b = nxt()
+                recorded[i].append({k: np.array(v) for k, v in b.items()})
+                return b
+
+            c.private_iter.next = record
+        metrics = [tr.step(t) for t in range(STEPS)]
+    finally:
+        trace.disable()
+    for c in tr.clients:
+        del c.private_iter.next
+    return {"trainer": tr, "metrics": metrics, "recorded": recorded,
+            "events": tracer.events()}
+
+
+@pytest.fixture(scope="module")
+def fleets():
+    """The same fleet twice: its dataset on the device (the CPU reports no
+    memory limit), and on the host because the fits check refuses."""
+    resident = _run_fleet()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr("repro.core.runtime.fits_on_device", lambda nbytes: False)
+        host = _run_fleet()
+    return resident, host
+
+
+def _where_data_lives(run):
+    instant, = [e for e in run["events"] if e["name"] == "data/resident"]
+    spans = [e for e in run["events"] if e["ph"] == "X" and
+             e["name"] in ("data/private", "data/public", "data/publish")]
+    return instant["args"], spans
+
+
+def test_resident_dataset_trains_bitwise_as_the_host_path(fleets):
+    resident, host = fleets
+    tr = resident["trainer"]
+    assert tr.public.resident and all(c.private_iter.resident
+                                      for c in tr.clients)
+    assert not host["trainer"].public.resident
+    assert resident["metrics"] == host["metrics"]  # every loss, every step
+    assert any(m[f"c{i}/distill_active"] for m in resident["metrics"]
+               for i in (0, 1))  # the published windows were distilled
+
+
+def test_data_location_is_in_the_trace(fleets):
+    from repro.data.pipeline import dataset_nbytes
+
+    for run, on_device in zip(fleets, (True, False)):
+        args, spans = _where_data_lives(run)
+        data = run["trainer"]._data
+        assert args["on_device"] is on_device
+        assert args["nbytes"] == (
+            sum(v.nbytes for v in data.rows.values()) if on_device
+            else dataset_nbytes(data))
+        assert {e["name"] for e in spans} == {"data/private", "data/public",
+                                              "data/publish"}
+        assert all(e["args"]["resident"] is on_device for e in spans)
+        # what crossed host→device: the rows' int32 indices on the device
+        # path, the rows themselves (float32 8x8 RGB images) on the host
+        bs = run["trainer"].run_cfg.batch_size
+        private = [e["args"]["nbytes"] for e in spans
+                   if e["name"] == "data/private"]
+        assert set(private) == {bs * 4 if on_device else bs * (8 * 8 * 3 + 1)
+                                * 4}
+
+
+def test_recording_hook_sees_device_batches_equal_to_the_host_gather(fleets):
+    resident, host = fleets
+    for i in (0, 1):
+        assert len(resident["recorded"][i]) == STEPS  # one per step
+        for dev, ref in zip(resident["recorded"][i], host["recorded"][i]):
+            assert set(dev) == set(ref) == {"images", "labels"}
+            for k in dev:
+                # the host batch as the jit boundary uploads it: int64
+                # labels become int32 there, as on the device
+                assert dev[k].dtype == jnp.asarray(ref[k]).dtype
+                assert dev[k].tobytes() == ref[k].astype(dev[k].dtype) \
+                    .tobytes()
+
+
+def test_reinit_client_rebuilds_its_iterator_on_the_resident_data(fleets):
+    resident, _ = fleets
+    tr = resident["trainer"]
+    tr.reinit_client(1)
+    it = tr.clients[1].private_iter
+    assert it.resident and it.arrays is tr._data
+    first = it.next()  # the stream is rewound to its start
+    assert isinstance(first["images"], jax.Array)
+    for k, v in resident["recorded"][1][0].items():
+        assert np.array(first[k]).tobytes() == v.tobytes()
+
+
+@pytest.mark.parametrize("in_use", [0, 2**20])
+def test_dataset_over_the_limit_stays_on_the_host(monkeypatch, in_use):
+    """A device that reports 4 KiB more than it has in use: the dataset
+    stays on the host, also where it is under a tenth of the device's
+    limit but over a tenth of what is free."""
+    from test_comm import _make_trainer
+
+    import repro.data.pipeline as pipeline
+    from repro.obs import tracer as trace
+
+    monkeypatch.setattr(pipeline, "device_memory_stats", lambda: {
+        "bytes_limit": in_use + 4096, "bytes_in_use": in_use})
+    tracer = trace.enable()
+    try:
+        tr = _make_trainer("prediction_topk", K=2, labels=4)
+    finally:
+        trace.disable()
+    instant, = [e for e in tracer.events() if e["name"] == "data/resident"]
+    nbytes = instant["args"]["nbytes"]
+    assert nbytes > pipeline.RESIDENT_SHARE * 4096
+    if in_use:
+        assert nbytes < pipeline.RESIDENT_SHARE * (in_use + 4096)
+    assert instant["args"]["on_device"] is False
+    assert not tr.public.resident
+    assert not any(c.private_iter.resident for c in tr.clients)
+    assert isinstance(tr.clients[0].private_iter.next()["images"],
+                      np.ndarray)
