@@ -25,6 +25,7 @@ _MODULES = [
     "deepseek_v3_671b",
     "zamba2_7b",
     "arctic_480b",
+    "nemotron3_nano",
     "resnet",
 ]
 
@@ -39,6 +40,7 @@ ARCH_IDS = [
     "deepseek-v3-671b",
     "zamba2-7b",
     "arctic-480b",
+    "nemotron3-nano",
 ]
 
 

@@ -1,5 +1,4 @@
-"""Adapter applying MHD to language-model clients (beyond-paper extension,
-DESIGN.md §7.4).
+"""Adapter applying MHD to language-model clients (beyond-paper extension).
 
 For an LM client the MHD "sample" is a *token position* on the public text
 pool: the prediction is the next-token distribution, the embedding ξ_i is the
@@ -8,7 +7,7 @@ into the (B', C) / (m, B', C) layout that core/mhd.py expects, with
 B' = batch · (T−1) next-token positions.
 
 Every assigned architecture works through this adapter (the MHD math never
-looks inside the backbone — see DESIGN.md §5).
+looks inside the backbone).
 """
 from __future__ import annotations
 
@@ -26,7 +25,8 @@ def lm_mhd_outputs(bundle: ModelBundle, params, batch: Dict[str, Any],
     """Run an LM and flatten to MHD client outputs.
 
     Returns {"embedding": (B', D), "logits": (B', V), "aux_logits": (m, B', V),
-             "labels": (B',), "sample_rows": (B',)} where labels are the next
+             "labels": (B',), "sample_rows": (B',), "aux_loss": ()} plus a
+    MoE model's "moe_stats" (`transformer.apply_lm`), where labels are the next
     tokens (used as the private CE target) and sample_rows maps each
     position back to its source sequence (per-domain eval aggregation).
 
@@ -78,9 +78,11 @@ def lm_mhd_outputs(bundle: ModelBundle, params, batch: Dict[str, Any],
             rows = rows[keep]
             if aux_flat is not None:
                 aux_flat = aux_flat[:, keep]
-    return {"embedding": emb, "logits": lg, "aux_logits": aux_flat,
-            "labels": lab, "sample_rows": rows,
-            "aux_loss": out["aux_loss"]}
+    res = {"embedding": emb, "logits": lg, "aux_logits": aux_flat,
+           "labels": lab, "sample_rows": rows, "aux_loss": out["aux_loss"]}
+    if "moe_stats" in out:
+        res["moe_stats"] = out["moe_stats"]
+    return res
 
 
 def lm_mhd_loss(bundle: ModelBundle, params, private_batch, public_batch,

@@ -99,6 +99,20 @@ def _batch_nbytes(batches: Sequence[Dict], resident: bool) -> int:
     return rows * np.dtype(INDEX_DTYPE).itemsize
 
 
+# per client step, over its forward passes and MoE layers: pairs routed to
+# the experts this device holds, and those of the busiest held expert
+MOE_COUNTERS = ("moe/rows_held", "moe/max_expert_rows")
+
+
+def _moe_metrics(*outs) -> Dict[str, Any]:
+    """A MoE client's step counters from its forward passes' outputs
+    (none for a model without experts)."""
+    if "moe_stats" not in outs[0]:
+        return {}
+    stats = sum(o["moe_stats"] for o in outs)
+    return dict(zip(MOE_COUNTERS, (stats[0], stats[1])))
+
+
 @dataclasses.dataclass
 class RunConfig:
     steps: int = 1000
@@ -317,6 +331,7 @@ class DecentralizedTrainer:
                                                teachers, mhd_cfg, rng)
                 if out_priv.get("aux_loss") is not None:
                     loss = loss + out_priv["aux_loss"]
+                metrics.update(_moe_metrics(out_priv, out_pub))
                 return loss, metrics
 
             def update(params, opt_state, private_batch, public_batch,
@@ -350,7 +365,7 @@ class DecentralizedTrainer:
                 loss = ce
                 if out.get("aux_loss") is not None:
                     loss = loss + out["aux_loss"]
-                return loss, {"ce": ce}
+                return loss, {"ce": ce, **_moe_metrics(out)}
 
             def update(params, opt_state, private_batch, step):
                 (loss, metrics), grads = jax.value_and_grad(
@@ -726,6 +741,10 @@ class DecentralizedTrainer:
             with trace.span("runtime/wait", client=c.client_id, step=t):
                 out = {f"c{c.client_id}/{k}": float(v)
                        for k, v in metrics.items()}
+            for k in MOE_COUNTERS:
+                if k in metrics:
+                    trace.counter(k, out[f"c{c.client_id}/{k}"],
+                                  client=c.client_id, step=t)
             trace.complete(
                 "runtime/supervised" if teachers is None
                 else "runtime/distill",

@@ -51,6 +51,18 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128,
     return ssd_chunked(x, dt, A, B, C, D, chunk_size=chunk)
 
 
+def moe_gmm(lhs, rhs, group_sizes):
+    """Grouped matmul over the experts a device holds: each group of
+    ``lhs`` rows times its expert's matrix of ``rhs``, float32 out
+    (`kernels/moe_gmm`; `ref.moe_gmm_ref` off a TPU). Rows past the last
+    group are the caller's to mask."""
+    if _default_use_pallas():
+        from repro.kernels.moe_gmm import gmm
+
+        return gmm(lhs, rhs, group_sizes)
+    return REF.moe_gmm_ref(lhs, rhs, group_sizes)
+
+
 def topk_wire(logits, k: int = 32, use_pallas: bool | None = None):
     """MHD exchange wire format: (top-k vals, idx, logsumexp)."""
     use = _default_use_pallas() if use_pallas is None else use_pallas

@@ -102,3 +102,14 @@ def emb_dist_ref(student_emb, teacher_emb, eps: float = 1e-8):
     s = s / (jnp.linalg.norm(s, axis=-1, keepdims=True) + eps)
     t = t / (jnp.linalg.norm(t, axis=-1, keepdims=True) + eps)
     return jnp.sum(jnp.square(s - t), axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul of the experts a device holds
+# ---------------------------------------------------------------------------
+
+def moe_gmm_ref(lhs, rhs, group_sizes):
+    """Each group of ``lhs`` rows (m, k) times its matrix of ``rhs``
+    (groups, k, n), float32; rows past the last group give zeros."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32)

@@ -3,7 +3,7 @@
 One ``ModelConfig`` describes every assigned architecture. Depth is expressed
 as *stages*: a stage is a homogeneous repeat-unit (list of ``LayerSpec``)
 scanned ``repeats`` times — this keeps HLO size O(unit) for 62..100-layer
-models (DESIGN.md §9) while expressing heterogeneous patterns
+models while expressing heterogeneous patterns
 (gemma3 5 local : 1 global, llama-vision 1 cross : 4 self,
 zamba2 shared-attention every 6th block).
 """
@@ -39,9 +39,28 @@ class MoEConfig:
     top_k: int = 2
     d_ff_expert: int = 2048
     num_shared_experts: int = 0  # deepseek: 1 shared expert
-    capacity_factor: float = 1.25
+    d_ff_shared: int = 0  # shared expert width; 0 = shared x d_ff_expert
+    capacity_factor: float = 1.25  # moe_a2a's per-device slot buffer only
     router_aux_weight: float = 0.01
     router_dtype: str = "float32"
+    # sigmoid routers (deepseek-v3, nemotron-h): a correction bias added
+    # to the scores for the expert choice only, and a factor on the
+    # renormalised weights of the chosen experts
+    router_bias: bool = False
+    routed_scaling: float = 1.0
+    # expert parallelism: this device holds experts
+    # [expert_offset, expert_offset + experts_held) of num_experts
+    # (0 = all); the router still scores every expert
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def shared_width(self) -> int:
+        return self.d_ff_shared or self.num_shared_experts * self.d_ff_expert
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,8 +79,12 @@ class MambaConfig:
     expand: int = 2
     head_dim: int = 64
     chunk_size: int = 256
+    n_groups: int = 1  # groups of B and C; head h reads group h // (H / G)
+    n_heads: int = 0  # explicit head count (nemotron-h); 0 = from expand
 
     def d_inner(self, d_model: int) -> int:
+        if self.n_heads:
+            return self.n_heads * self.head_dim
         return self.expand * d_model
 
     def num_heads(self, d_model: int) -> int:
@@ -70,7 +93,7 @@ class MambaConfig:
 
 @dataclasses.dataclass(frozen=True)
 class VisionStubConfig:
-    """VLM frontend stub: precomputed patch embeddings (DESIGN.md §5)."""
+    """VLM frontend stub: precomputed patch embeddings."""
 
     num_patches: int = 1024
     embed_dim: int = 1280  # raw vision-encoder hidden; projector is in-model
@@ -112,7 +135,8 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None
     # norms / activations
     norm: str = "rmsnorm"  # rmsnorm | layernorm
-    act: str = "silu"  # silu | gelu
+    norm_eps: float = 1e-6
+    act: str = "silu"  # silu | gelu | relu2 (ffn and experts)
     tie_embeddings: bool = False
     pos_embed: str = "rope"  # rope | learned | sinusoidal | none
     scale_embeddings: bool = False  # gemma: embed * sqrt(d_model)
@@ -158,6 +182,18 @@ class ModelConfig:
 def uniform_stages(num_layers: int, spec: LayerSpec) -> Tuple[Stage, ...]:
     """All layers identical: one stage scanning `num_layers` single-layer units."""
     return (Stage(block=(spec,), repeats=num_layers),)
+
+
+def run_length_stages(specs: Sequence[LayerSpec]) -> Tuple[Stage, ...]:
+    """A layer sequence with no period (nemotron-h's hybrid pattern): one
+    stage per run of identical consecutive layers."""
+    stages: List[Stage] = []
+    for spec in specs:
+        if stages and stages[-1].block == (spec,):
+            stages[-1] = Stage(block=(spec,), repeats=stages[-1].repeats + 1)
+        else:
+            stages.append(Stage(block=(spec,), repeats=1))
+    return tuple(stages)
 
 
 def patterned_stages(

@@ -1,10 +1,9 @@
 """Expert-parallel MoE with explicit all-to-all dispatch (§Perf, Pair B).
 
-The portable scatter-based dispatch (moe.py) lets XLA SPMD partition a
-global scatter — which replicates the (N·k, D) update stream across the
-expert ('model') axis and is catastrophically collective-bound for
-256-expert configs (EXPERIMENTS.md §Roofline: deepseek train_4k baseline
-collective term ≈ 1750 s/step-equivalent).
+A global dispatch left to XLA SPMD partitioning replicates the (N·k, D)
+update stream across the expert ('model') axis and is collective-bound
+for 256-expert configs; ``moe.py`` is the one-device path, told which
+experts it holds, with no exchange at all.
 
 This module hand-writes the canonical expert-parallel schedule in a fully
 manual ``jax.shard_map`` over every mesh axis:
@@ -67,8 +66,9 @@ _bf16_grad_boundary.defvjp(_bf16_fwd, _bf16_bwd)
 
 def moe_apply_a2a(params, x, cfg: MoEConfig, act: str = "silu",
                   scoring: str = "softmax") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Drop-in replacement for moe.moe_apply when a 'model' mesh axis exists
-    (falls back to the scatter implementation otherwise — CPU tests)."""
+    """Drop-in replacement for moe.moe_apply's (output, aux loss) when a
+    'model' mesh axis exists (falls back to moe.moe_apply otherwise —
+    CPU tests)."""
     sizes = _mesh_axes()
     n_model = sizes.get(MODEL_AXIS, 1)
     token_axes = tuple(a for a in ("pod", "data", MODEL_AXIS) if a in sizes)
@@ -85,7 +85,7 @@ def moe_apply_a2a(params, x, cfg: MoEConfig, act: str = "silu",
     if (n_model <= 1 or E % n_model != 0 or xf.shape[0] % n_tok_shards != 0):
         from repro.models.moe import moe_apply
 
-        return moe_apply(params, x, cfg, act, scoring)
+        return moe_apply(params, x, cfg, act, scoring)[:2]
 
     N_dev = xf.shape[0] // n_tok_shards  # tokens per device
     C = max(int(math.ceil(N_dev * K / E * cfg.capacity_factor)), 1)

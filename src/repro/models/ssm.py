@@ -1,15 +1,18 @@
 """Mamba2 (SSD — state-space duality, arXiv:2405.21060) block.
 
-TPU adaptation (DESIGN.md §3): instead of the CUDA selective-scan, the
-sequence is processed in chunks — intra-chunk interactions are a dense
-(L_c × L_c) masked matmul (MXU-friendly), inter-chunk state is carried by a
+TPU adaptation: instead of the CUDA selective-scan, the sequence is
+processed in chunks — intra-chunk interactions are a dense (L_c × L_c)
+masked matmul (MXU-friendly), inter-chunk state is carried by a
 ``lax.scan`` over chunks. The Pallas kernel (kernels/ssd_scan.py) fuses the
 intra-chunk compute per (chunk, head) tile in VMEM; this module provides the
 pure-jnp implementation used on CPU and as the kernel oracle.
 
 Scalar-identities follow the Mamba2 paper: per head h with state N and head
 dim P,   h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_tᵀ,   y_t = C_tᵀ h_t + D x_t.
-ngroups = 1 (B, C shared across heads), as in the released models.
+B and C come in ``n_groups`` groups, head h reading group h // (H / G):
+one group in the Mamba2 releases, 8 in Nemotron-H. The gated RMSNorm
+before the output projection normalises each group's d_inner / G
+channels on their own.
 """
 from __future__ import annotations
 
@@ -35,7 +38,7 @@ def init_mamba2(key, d_model: int, cfg: MambaConfig, dtype=jnp.float32):
     k = jax.random.split(key, 6)
     d_in = cfg.d_inner(d_model)
     H = cfg.num_heads(d_model)
-    N = cfg.d_state
+    N = cfg.n_groups * cfg.d_state
     conv_ch = d_in + 2 * N  # x, B, C all pass through the causal conv
     # dt_bias init so that softplus(dt_bias) spans ~[1e-3, 1e-1] (mamba2 default)
     u = jax.random.uniform(k[3], (H,))
@@ -59,12 +62,36 @@ def _split_in_proj(z_xbc_dt, d_in: int, N: int, H: int):
     return z, xbc, dt
 
 
+def _per_group(ssd_one, x, dt, A, B, C, D, chunk_size):
+    """``ssd_one`` (one group of B and C) run once per group, over that
+    group's heads. B, C: (Bt, T, N), one group, or (Bt, T, G, N)."""
+    if B.ndim == 3:
+        return ssd_one(x, dt, A, B, C, D, chunk_size)
+    G = B.shape[2]
+    if G == 1:
+        return ssd_one(x, dt, A, B[:, :, 0], C[:, :, 0], D, chunk_size)
+    Bt, T, H, P = x.shape
+    hg = H // G
+    y, h = jax.vmap(
+        lambda x_, dt_, A_, B_, C_, D_: ssd_one(x_, dt_, A_, B_, C_, D_,
+                                                chunk_size),
+        in_axes=(2, 2, 0, 2, 2, 0), out_axes=(2, 1))(
+        x.reshape(Bt, T, G, hg, P), dt.reshape(Bt, T, G, hg),
+        A.reshape(G, hg), B, C, D.reshape(G, hg))
+    return y.reshape(Bt, T, H, P), h.reshape(Bt, H, P, B.shape[-1])
+
+
 def ssd_reference(x, dt, A, B, C, D, chunk_size: int = 0):
     """Sequential-scan oracle.
 
-    x: (Bt, T, H, P); dt: (Bt, T, H); A: (H,); B, C: (Bt, T, N); D: (H,)
+    x: (Bt, T, H, P); dt: (Bt, T, H); A: (H,); B, C: (Bt, T, N) or
+    (Bt, T, G, N); D: (H,)
     returns y: (Bt, T, H, P), final_state: (Bt, H, P, N)
     """
+    return _per_group(_ssd_reference_one, x, dt, A, B, C, D, chunk_size)
+
+
+def _ssd_reference_one(x, dt, A, B, C, D, chunk_size: int = 0):
     Bt, T, H, P = x.shape
     N = B.shape[-1]
     decay = jnp.exp(dt * A[None, None, :])  # (Bt, T, H)
@@ -92,7 +119,12 @@ def ssd_reference(x, dt, A, B, C, D, chunk_size: int = 0):
 
 
 def ssd_chunked(x, dt, A, B, C, D, chunk_size: int = 64):
-    """Chunked SSD (training path): O(T·L_c) with MXU-dense intra-chunk math."""
+    """Chunked SSD (training path): O(T·L_c) with MXU-dense intra-chunk
+    math. Shapes as `ssd_reference`."""
+    return _per_group(_ssd_chunked_one, x, dt, A, B, C, D, chunk_size)
+
+
+def _ssd_chunked_one(x, dt, A, B, C, D, chunk_size: int = 64):
     Bt, T, H, P = x.shape
     N = B.shape[-1]
     L = chunk_size
@@ -144,28 +176,41 @@ def ssd_chunked(x, dt, A, B, C, D, chunk_size: int = 64):
     return y.astype(x.dtype), h_final
 
 
-def mamba2_apply(params, x, cfg: MambaConfig, *, use_chunked: bool = True):
+def gated_norm(params, y, z, groups: int, eps: float = 1e-6):
+    """``rmsnorm(y * silu(z))``, each of ``groups`` groups of channels
+    normalised on its own, then scaled."""
+    g = (y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype))
+    if groups == 1:
+        return norm_apply(params, g, eps=eps)
+    lead, d = g.shape[:-1], g.shape[-1]
+    g32 = g.astype(jnp.float32).reshape(lead + (groups, d // groups))
+    var = jnp.mean(jnp.square(g32), axis=-1, keepdims=True)
+    g32 = (g32 * jax.lax.rsqrt(var + eps)).reshape(lead + (d,))
+    return (g32 * params["scale"].astype(jnp.float32)).astype(g.dtype)
+
+
+def mamba2_apply(params, x, cfg: MambaConfig, *, use_chunked: bool = True,
+                 eps: float = 1e-6):
     """Full-sequence forward. x: (B, T, D) -> (B, T, D)."""
     B_, T, D_model = x.shape
     d_in = cfg.d_inner(D_model)
     H = cfg.num_heads(D_model)
-    N = cfg.d_state
+    G, N = cfg.n_groups, cfg.d_state
 
     zxd = jnp.einsum("...d,de->...e", x, params["in_proj"],
                      preferred_element_type=jnp.float32).astype(x.dtype)
-    z, xbc, dt_raw = _split_in_proj(zxd, d_in, N, H)
+    z, xbc, dt_raw = _split_in_proj(zxd, d_in, G * N, H)
     xbc = jax.nn.silu(causal_conv1d_apply(params["conv"], xbc))
     xc = xbc[..., :d_in].reshape(B_, T, H, cfg.head_dim)
-    Bmat = xbc[..., d_in : d_in + N]
-    Cmat = xbc[..., d_in + N :]
+    Bmat = xbc[..., d_in : d_in + G * N].reshape(B_, T, G, N)
+    Cmat = xbc[..., d_in + G * N :].reshape(B_, T, G, N)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
 
     ssd = ssd_chunked if (use_chunked and T % cfg.chunk_size == 0) else ssd_reference
     y, _ = ssd(xc, dt, A, Bmat, Cmat, params["D"],
                chunk_size=cfg.chunk_size)
-    y = y.reshape(B_, T, d_in)
-    y = norm_apply(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype))
+    y = gated_norm(params["norm"], y.reshape(B_, T, d_in), z, G, eps)
     return jnp.einsum("...e,ed->...d", y, params["out_proj"],
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
@@ -174,39 +219,42 @@ def init_mamba2_cache(batch: int, d_model: int, cfg: MambaConfig,
                       dtype=jnp.float32):
     d_in = cfg.d_inner(d_model)
     H = cfg.num_heads(d_model)
+    conv_ch = d_in + 2 * cfg.n_groups * cfg.d_state
     return {
         "ssm": jnp.zeros((batch, H, cfg.head_dim, cfg.d_state), jnp.float32),
-        "conv": jnp.zeros((batch, cfg.d_conv - 1, d_in + 2 * cfg.d_state), dtype),
+        "conv": jnp.zeros((batch, cfg.d_conv - 1, conv_ch), dtype),
         "index": jnp.zeros((), jnp.int32),
     }
 
 
-def mamba2_decode(params, x, cache, cfg: MambaConfig):
+def mamba2_decode(params, x, cache, cfg: MambaConfig, *, eps: float = 1e-6):
     """Single-token step. x: (B, 1, D)."""
     B_, _, D_model = x.shape
     d_in = cfg.d_inner(D_model)
     H = cfg.num_heads(D_model)
-    N = cfg.d_state
+    G, N = cfg.n_groups, cfg.d_state
 
     zxd = jnp.einsum("btd,de->bte", x, params["in_proj"],
                      preferred_element_type=jnp.float32).astype(x.dtype)[:, 0]
-    z, xbc, dt_raw = _split_in_proj(zxd, d_in, N, H)
+    z, xbc, dt_raw = _split_in_proj(zxd, d_in, G * N, H)
     xbc, conv_state = causal_conv1d_step(params["conv"], xbc, cache["conv"])
     xbc = jax.nn.silu(xbc)
     xc = xbc[..., :d_in].reshape(B_, H, cfg.head_dim).astype(jnp.float32)
-    Bmat = xbc[..., d_in : d_in + N].astype(jnp.float32)
-    Cmat = xbc[..., d_in + N :].astype(jnp.float32)
+    # head h reads group h // (H / G)
+    Bmat = jnp.repeat(xbc[..., d_in : d_in + G * N].reshape(B_, G, N),
+                      H // G, axis=1).astype(jnp.float32)  # (B,H,N)
+    Cmat = jnp.repeat(xbc[..., d_in + G * N :].reshape(B_, G, N),
+                      H // G, axis=1).astype(jnp.float32)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])  # (B,H)
     A = -jnp.exp(params["A_log"])
     decay = jnp.exp(dt * A[None, :])  # (B,H)
 
     h = cache["ssm"] * decay[:, :, None, None] + (
-        (dt[:, :, None] * xc)[..., None] * Bmat[:, None, None, :]
+        (dt[:, :, None] * xc)[..., None] * Bmat[:, :, None, :]
     )
-    y = jnp.einsum("bhpn,bn->bhp", h, Cmat) + xc * params["D"][None, :, None]
-    y = y.reshape(B_, d_in)
-    y = norm_apply(params["norm"],
-                   (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype))
+    y = jnp.einsum("bhpn,bhn->bhp", h, Cmat) + xc * params["D"][None, :, None]
+    y = gated_norm(params["norm"], y.reshape(B_, d_in).astype(x.dtype), z,
+                   G, eps)
     out = jnp.einsum("be,ed->bd", y, params["out_proj"],
                      preferred_element_type=jnp.float32).astype(x.dtype)
     new_cache = {"ssm": h, "conv": conv_state, "index": cache["index"] + 1}

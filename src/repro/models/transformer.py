@@ -8,7 +8,7 @@ deepseek's dense-then-MoE split — are all expressed as unit patterns.
 
 Public API (pure functions):
   init_lm(key, cfg)                      -> params
-  apply_lm(params, cfg, batch, ...)      -> {"logits", "hidden", "aux_heads", "aux_loss"}
+  apply_lm(params, cfg, batch, ...)      -> {"logits", "hidden", "aux_heads", "aux_loss"[, "moe_stats"]}
   lm_loss(params, cfg, batch)            -> (loss, metrics)
   init_lm_cache(cfg, batch, cache_len)   -> caches
   decode_step(params, cfg, token, caches, ...) -> (logits, caches)
@@ -167,12 +167,14 @@ def _sinusoidal(T: int, D: int) -> jnp.ndarray:
 
 def _layer_forward(lp, cfg: ModelConfig, spec: LayerSpec, x, *,
                    shared_attn_params, cross_src, enc_out, mask_kind_override=None):
-    """One layer (full-sequence path). Returns (x, aux_loss)."""
+    """One layer (full-sequence path). Returns (x, aux_loss, moe_stats):
+    moe_stats as `moe.moe_apply` returns them, zeros without experts."""
     aux = jnp.zeros((), jnp.float32)
+    stats = jnp.zeros((2,), jnp.float32)
     rope = cfg.rope_theta if cfg.pos_embed == "rope" else None
 
     if spec.attn in ("full", "swa"):
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
         if cfg.mla is not None:
             a = MLA.mla_apply(lp["attn"], h, cfg.mla, cfg.num_heads,
                               rope_theta=cfg.rope_theta)
@@ -184,77 +186,83 @@ def _layer_forward(lp, cfg: ModelConfig, spec: LayerSpec, x, *,
                 rope_theta=rope, logit_softcap=cfg.attn_logit_softcap)
         x = x + a
     elif spec.attn == "cross":
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
         a = L.attention_apply(
             lp["attn"], _attn_dims(cfg, cross=True), h,
             mask_kind="none", kv_src=cross_src, rope_theta=None)
         x = x + jnp.tanh(lp["cross_gate"]).astype(x.dtype) * a
     elif spec.attn == "mamba2":
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
-        x = x + SSM.mamba2_apply(lp["attn"], h, cfg.mamba)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
+        x = x + SSM.mamba2_apply(lp["attn"], h, cfg.mamba, eps=cfg.norm_eps)
 
     if spec.shared_attn:
-        h = L.norm_apply(shared_attn_params["norm"], x, cfg.norm)
+        h = L.norm_apply(shared_attn_params["norm"], x, cfg.norm, cfg.norm_eps)
         a = L.attention_apply(
             shared_attn_params["attn"], _attn_dims(cfg), h,
             mask_kind="causal", rope_theta=rope)
         x = x + a
 
     if spec.cross_attn:
-        h = L.norm_apply(lp["xattn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["xattn_norm"], x, cfg.norm, cfg.norm_eps)
         a = L.attention_apply(
             lp["xattn"], _attn_dims(cfg), h,
             mask_kind="none", kv_src=enc_out, rope_theta=None)
         x = x + a
 
     if spec.ffn == "dense":
-        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm, cfg.norm_eps)
         x = x + L.mlp_apply(lp["ffn"], h, cfg.act)
     elif spec.ffn in ("moe", "moe_dense_parallel"):
-        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm, cfg.norm_eps)
         if cfg.moe_impl == "a2a":
             from repro.models.moe_a2a import moe_apply_a2a
 
             y, moe_aux = moe_apply_a2a(lp["ffn"], h, cfg.moe, cfg.act,
                                        scoring=cfg.moe_scoring)
         else:
-            y, moe_aux = MOE.moe_apply(lp["ffn"], h, cfg.moe, cfg.act,
-                                       scoring=cfg.moe_scoring)
+            y, moe_aux, stats = MOE.moe_apply(lp["ffn"], h, cfg.moe, cfg.act,
+                                              scoring=cfg.moe_scoring)
         if spec.ffn == "moe_dense_parallel":
             y = y + L.mlp_apply(lp["ffn_dense"], h, cfg.act)
         x = x + y
         aux = aux + moe_aux
     x = maybe_shard(x, "batch", "seq", "model")
-    return x, aux
+    return x, aux, stats
 
 
 def _run_stages(params, cfg: ModelConfig, x, stages, prefix, *,
                 shared_attn_params=None, cross_src=None, enc_out=None,
                 mask_kind_override=None):
-    """Scan every stage's stacked units over x. Returns (x, total_aux)."""
-    total_aux = jnp.zeros((), jnp.float32)
+    """Scan every stage's stacked units over x. Returns (x, total_aux,
+    moe_stats summed over the layers)."""
+    total = (jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.float32))
 
     for si, stage in enumerate(stages):
         stacked = params[f"{prefix}{si}"]
 
         def unit_fn(carry, unit_params, _stage=stage):
-            h, aux_acc = carry
+            h, (aux_acc, stats_acc) = carry
             for li, spec in enumerate(_stage.block):
-                h, aux = _layer_forward(
+                h, aux, stats = _layer_forward(
                     unit_params[f"layer{li}"], cfg, spec, h,
                     shared_attn_params=shared_attn_params,
                     cross_src=cross_src, enc_out=enc_out,
                     mask_kind_override=mask_kind_override)
                 aux_acc = aux_acc + aux
-            return (h, aux_acc), None
+                stats_acc = stats_acc + stats
+            return (h, (aux_acc, stats_acc)), None
 
         if cfg.remat != "none":
-            unit_fn = jax.checkpoint(unit_fn, prevent_cse=False)
+            # inside a scan the recomputation cannot be merged with the
+            # forward; a unit called once needs CSE prevented, or XLA
+            # keeps the forward's activations and the remat saves nothing
+            unit_fn = jax.checkpoint(unit_fn,
+                                     prevent_cse=stage.repeats == 1)
 
         r1 = _nested_factor(stage.repeats) if cfg.remat == "nested" else 0
         if stage.repeats == 1:
-            (x, total_aux), _ = unit_fn(
-                (x, total_aux), jax.tree.map(lambda a: a[0], stacked))
+            (x, total), _ = unit_fn(
+                (x, total), jax.tree.map(lambda a: a[0], stacked))
         elif r1:
             # √-depth remat: outer scan over r1 groups, each group a
             # checkpointed inner scan over r2 units — residual stacks hold
@@ -266,13 +274,12 @@ def _run_stages(params, cfg: ModelConfig, x, stages, prefix, *,
 
             grouped = jax.tree.map(
                 lambda a: a.reshape((r1, r2) + a.shape[1:]), stacked)
-            (x, total_aux), _ = jax.lax.scan(
+            (x, total), _ = jax.lax.scan(
                 jax.checkpoint(group_fn, prevent_cse=False),
-                (x, total_aux), grouped)
+                (x, total), grouped)
         else:
-            (x, total_aux), _ = jax.lax.scan(
-                unit_fn, (x, total_aux), stacked)
-    return x, total_aux
+            (x, total), _ = jax.lax.scan(unit_fn, (x, total), stacked)
+    return (x,) + total
 
 
 def _nested_factor(repeats: int) -> int:
@@ -310,9 +317,9 @@ def encode_audio(params, cfg: ModelConfig, frames):
     x = maybe_shard(x, "batch", "seq", "model")
     enc_stage = (Stage(block=(LayerSpec(attn="full", ffn="dense"),),
                        repeats=cfg.encoder.num_layers),)
-    x, _ = _run_stages(params["encoder"], cfg, x, enc_stage, "stage",
+    x, _, _ = _run_stages(params["encoder"], cfg, x, enc_stage, "stage",
                        mask_kind_override="none")
-    return L.norm_apply(params["encoder"]["final_norm"], x, cfg.norm)
+    return L.norm_apply(params["encoder"]["final_norm"], x, cfg.norm, cfg.norm_eps)
 
 
 def _heads(params, cfg: ModelConfig, hidden):
@@ -334,7 +341,9 @@ def apply_lm(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
     batch: {"tokens": (B,T)} plus optionally "vision_embeds" (B,P,v_dim)
     or "audio_frames" (B,T_enc,f_dim).
     Returns dict with hidden (B,T,D), logits (B,T,V), aux_heads (m,B,T,V)|None,
-    aux_loss scalar, and (if cfg.mtp) mtp_hidden.
+    aux_loss scalar, (with experts) moe_stats (2,) = rows routed to the
+    held experts and rows of the busiest one, summed over the MoE layers,
+    and (if cfg.mtp) mtp_hidden.
     """
     tokens = batch["tokens"]
     x = _embed_tokens(params, cfg, tokens)
@@ -355,14 +364,16 @@ def apply_lm(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
         shared = {"attn": params["shared_attn"],
                   "norm": params["shared_attn_norm"]}
 
-    x, aux_loss = _run_stages(params, cfg, x, cfg.stages, "stage",
-                              shared_attn_params=shared,
-                              cross_src=cross_src, enc_out=enc_out)
-    hidden = L.norm_apply(params["final_norm"], x, cfg.norm)
+    x, aux_loss, moe_stats = _run_stages(params, cfg, x, cfg.stages, "stage",
+                                         shared_attn_params=shared,
+                                         cross_src=cross_src, enc_out=enc_out)
+    hidden = L.norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits, aux_logits = _heads(params, cfg, hidden)
 
     out = {"hidden": hidden, "logits": logits, "aux_heads": aux_logits,
            "aux_loss": aux_loss}
+    if cfg.moe is not None:
+        out["moe_stats"] = moe_stats
 
     if cfg.mtp:
         # DeepSeek MTP: predict t+2 from [h_t ; emb(tok_{t+1})]
@@ -370,8 +381,8 @@ def apply_lm(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
         mtp_in = jnp.concatenate([hidden, emb_next.astype(hidden.dtype)], axis=-1)
         h = jnp.einsum("...e,ed->...d", mtp_in, params["mtp"]["proj"],
                        preferred_element_type=jnp.float32).astype(hidden.dtype)
-        h = L.norm_apply(params["mtp"]["norm"], h, cfg.norm)
-        h, _ = _layer_forward(params["mtp"]["layer"], cfg,
+        h = L.norm_apply(params["mtp"]["norm"], h, cfg.norm, cfg.norm_eps)
+        h, _, _ = _layer_forward(params["mtp"]["layer"], cfg,
                               LayerSpec(attn="full", ffn="dense"), h,
                               shared_attn_params=None, cross_src=None,
                               enc_out=None)
@@ -521,7 +532,7 @@ def _layer_decode(lp, cfg: ModelConfig, spec: LayerSpec, x, cache, *,
     rope = cfg.rope_theta if cfg.pos_embed == "rope" else None
     new_cache = dict(cache)
     if spec.attn in ("full", "swa"):
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
         if cfg.mla is not None:
             a, new_cache["attn"] = MLA.mla_decode(
                 lp["attn"], h, cache["attn"], cfg.mla, cfg.num_heads,
@@ -534,33 +545,33 @@ def _layer_decode(lp, cfg: ModelConfig, spec: LayerSpec, x, cache, *,
                 logit_softcap=cfg.attn_logit_softcap)
         x = x + a
     elif spec.attn == "cross":
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
         a = _cross_decode(lp["attn"], cfg, h, cache["attn"])
         x = x + jnp.tanh(lp["cross_gate"]).astype(x.dtype) * a
     elif spec.attn == "mamba2":
-        h = L.norm_apply(lp["attn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["attn_norm"], x, cfg.norm, cfg.norm_eps)
         a, new_cache["attn"] = SSM.mamba2_decode(lp["attn"], h, cache["attn"],
-                                                 cfg.mamba)
+                                                 cfg.mamba, eps=cfg.norm_eps)
         x = x + a
 
     if spec.shared_attn:
-        h = L.norm_apply(shared_attn_params["norm"], x, cfg.norm)
+        h = L.norm_apply(shared_attn_params["norm"], x, cfg.norm, cfg.norm_eps)
         a, new_cache["shared_attn"] = L.attention_decode(
             shared_attn_params["attn"], _attn_dims(cfg), h,
             cache["shared_attn"], rope_theta=rope)
         x = x + a
 
     if spec.cross_attn:
-        h = L.norm_apply(lp["xattn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["xattn_norm"], x, cfg.norm, cfg.norm_eps)
         x = x + _cross_decode(lp["xattn"], cfg, h, cache["xattn"])
 
     if spec.ffn == "dense":
-        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm)
+        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm, cfg.norm_eps)
         x = x + L.mlp_apply(lp["ffn"], h, cfg.act)
     elif spec.ffn in ("moe", "moe_dense_parallel"):
-        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm)
-        y, _ = MOE.moe_apply(lp["ffn"], h, cfg.moe, cfg.act,
-                             scoring=cfg.moe_scoring)
+        h = L.norm_apply(lp["ffn_norm"], x, cfg.norm, cfg.norm_eps)
+        y, _, _ = MOE.moe_apply(lp["ffn"], h, cfg.moe, cfg.act,
+                                scoring=cfg.moe_scoring)
         if spec.ffn == "moe_dense_parallel":
             y = y + L.mlp_apply(lp["ffn_dense"], h, cfg.act)
         x = x + y
@@ -654,6 +665,6 @@ def decode_step(params, cfg: ModelConfig, token, caches):
             x, new_caches[f"stage{si}"] = jax.lax.scan(
                 unit_fn, x, (stacked_p, stacked_c))
 
-    hidden = L.norm_apply(params["final_norm"], x, cfg.norm)
+    hidden = L.norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits, _ = _heads(params, cfg, hidden)
     return logits, new_caches

@@ -50,8 +50,8 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "Tracer", "active", "complete", "counter", "disable", "enable",
-    "flow_end", "flow_id", "flow_start", "get", "instant", "now", "span",
-    "set_anchor",
+    "flow_end", "flow_id", "flow_start", "get", "instant", "last", "now",
+    "span", "set_anchor",
 ]
 
 
@@ -207,6 +207,7 @@ class Tracer:
 # -- module-level hooks (the instrumented code calls these) ------------------
 
 _tracer: Optional[Tracer] = None
+_last: Optional[Tracer] = None  # the one `disable` took down, for readers
 
 
 def enable(capacity: int = 1 << 17, rank: int = 0,
@@ -219,13 +220,22 @@ def enable(capacity: int = 1 << 17, rank: int = 0,
 
 
 def disable() -> None:
-    """Back to no-op mode (the default)."""
-    global _tracer
+    """Back to no-op mode (the default). The tracer taken down stays
+    readable through `last` until the next one is enabled."""
+    global _tracer, _last
+    if _tracer is not None:
+        _last = _tracer
     _tracer = None
 
 
 def get() -> Optional[Tracer]:
     return _tracer
+
+
+def last() -> Optional[Tracer]:
+    """The tracer enabled most recently, whether or not it still is: what
+    a reader of a traced session's events reads once tracing is off."""
+    return _tracer if _tracer is not None else _last
 
 
 def active() -> bool:

@@ -28,16 +28,10 @@ def _decode_all(bundle, params, tokens, caches):
     "zamba2-7b",          # hybrid + shared attention block
     "deepseek-v3-671b",   # MLA absorbed decode + MoE
     "minitron-4b",        # relu2 dense
+    "nemotron3-nano",     # grouped Mamba2 + GQA + held relu2 experts
 ])
 def test_decode_matches_full_forward(arch):
-    import dataclasses
-
     cfg = get_reduced(arch)
-    if cfg.moe is not None:
-        # capacity dropping is seq-length dependent (full forward routes all
-        # positions jointly; decode routes one) — compare dropless
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     bundle = build_bundle(cfg)
     params = bundle.init(jax.random.PRNGKey(0))
     B, T = 2, 24

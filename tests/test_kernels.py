@@ -9,6 +9,7 @@ from repro.kernels import ref as REF
 from repro.kernels.dist_ce import dist_ce
 from repro.kernels.emb_dist import emb_dist
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.moe_gmm import gmm
 from repro.kernels.ssd_scan import ssd_scan
 
 
@@ -82,6 +83,53 @@ def test_emb_dist_sweep(B, E):
     r = REF.emb_dist_ref(s, t)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("sizes,k,n", [
+    ((5, 0, 130, 27), 128, 256),   # an empty group, rows past the groups
+    ((200, 56), 256, 200),         # n and the rows not a tile multiple
+])
+def test_moe_gmm_matches_ragged_dot(sizes, k, n):
+    """The grouped matmul kernels (forward, input and weight gradients)
+    in interpret mode against `jax.lax.ragged_dot`, float32 throughout;
+    rows past the last group are the caller's to mask."""
+    m = 300
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), k, n))
+    gs = jnp.asarray(sizes, jnp.int32)
+    rows = sum(sizes)
+    ct = jax.random.normal(jax.random.PRNGKey(2), (m, n))
+    mask = (jnp.arange(m) < rows)[:, None]
+
+    def loss(fn, a, b):
+        return jnp.sum(jnp.where(mask, fn(a, b), 0.0) * ct)
+
+    kernel = lambda a, b: gmm(a, b, gs, compute_dtype=jnp.float32,  # noqa
+                              interpret=True)
+    oracle = lambda a, b: REF.moe_gmm_ref(a, b, gs)  # noqa: E731
+    out, ref = kernel(lhs, rhs), oracle(lhs, rhs)
+    np.testing.assert_allclose(np.asarray(out[:rows]), np.asarray(ref[:rows]),
+                               rtol=1e-4, atol=1e-3)
+    g = jax.grad(lambda a, b: loss(kernel, a, b), argnums=(0, 1))(lhs, rhs)
+    g_ref = jax.grad(lambda a, b: loss(oracle, a, b), argnums=(0, 1))(lhs,
+                                                                      rhs)
+    np.testing.assert_allclose(np.asarray(g[0][:rows]),
+                               np.asarray(g_ref[0][:rows]),
+                               rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(g[1]), np.asarray(g_ref[1]),
+                               rtol=1e-4, atol=1e-2)
+
+
+def test_moe_gmm_in_bfloat16_is_within_rounding():
+    """The TPU operand type: bfloat16 products, float32 accumulation."""
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (256, 128))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 128))
+    gs = jnp.asarray([100, 120], jnp.int32)
+    out = gmm(lhs, rhs, gs, interpret=True)[:220]
+    ref = REF.moe_gmm_ref(lhs, rhs, gs)[:220]
+    assert out.dtype == jnp.float32
+    err = np.abs(np.asarray(out) - np.asarray(ref)).max()
+    assert err < 0.02 * np.abs(np.asarray(ref)).max()
 
 
 def test_ops_dispatch_cpu_uses_ref():

@@ -62,7 +62,7 @@ def test_forward_and_train_step(arch):
 
 @pytest.mark.parametrize("arch", ["gemma3-12b", "qwen2.5-32b", "mamba2-370m",
                                   "deepseek-v3-671b", "zamba2-7b",
-                                  "arctic-480b"])
+                                  "arctic-480b", "nemotron3-nano"])
 def test_decode_step_shapes(arch):
     cfg = get_reduced(arch)
     bundle = build_bundle(cfg)

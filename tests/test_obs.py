@@ -76,6 +76,20 @@ def test_enable_records_spans_and_disable_stops():
     assert tracer.rank == 3 and tracer.process_name == "r3"
 
 
+def test_last_tracer_stays_readable_after_disable():
+    first = trace.enable()
+    trace.counter("moe/rows_held", 7.0, client=0)
+    assert trace.last() is first
+    trace.disable()
+    assert trace.get() is None and trace.last() is first
+    assert [e["args"]["value"] for e in trace.last().events()] == [7.0]
+    trace.disable()  # a second disable keeps it
+    assert trace.last() is first
+    second = trace.enable()
+    assert trace.last() is second
+    trace.disable()
+
+
 def test_ring_buffer_drops_oldest_and_counts():
     tracer = trace.enable(capacity=4)
     for i in range(10):

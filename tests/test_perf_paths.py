@@ -61,7 +61,7 @@ def test_moe_a2a_falls_back_to_scatter_on_cpu():
                     capacity_factor=2.0)
     params = init_moe(jax.random.PRNGKey(0), 8, cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 8))
-    y1, a1 = moe_apply(params, x, cfg)
+    y1, a1, _ = moe_apply(params, x, cfg)
     y2, a2 = moe_apply_a2a(params, x, cfg)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5)
 

@@ -98,3 +98,67 @@ def test_device_batch_gather_reads_only_its_rows_on_v5e(one_chip):
         .memory_analysis()
     assert mem.argument_size_in_bytes > 1.2e9
     assert mem.temp_size_in_bytes < 0.1 * mem.argument_size_in_bytes
+
+
+def test_moe_gmm_compiles_for_v5e_at_nemotron_widths(one_chip):
+    """One MoE layer-pass of the Nemotron-H cell: 2048 tokens x 6 choices
+    of sorted rows against the 8 held experts' 2688 x 1856 matrices,
+    forward and both gradients, each call named for the trace."""
+    from repro.kernels.moe_gmm import gmm
+
+    def step(x, w, sizes):
+        return jax.grad(lambda x, w: jnp.sum(gmm(x, w, sizes)),
+                        argnums=(0, 1))(x, w)
+
+    text = _compiled_text(
+        step, jax.ShapeDtypeStruct((12288, 2688), jnp.float32,
+                                   sharding=one_chip),
+        jax.ShapeDtypeStruct((8, 2688, 1856), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip))
+    kernels = [line.split(" = ")[0] for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert sum("moe_gmm" in k for k in kernels) == 1  # input gradient
+    assert sum("moe_tgmm" in k for k in kernels) == 1  # weight gradient
+
+
+def test_hybrid_client_update_compiles_for_v5e(one_chip, monkeypatch):
+    """The trainer's client update for a Nemotron-H client as a TPU runs
+    it: Mamba2 with groups, attention and held experts through the
+    grouped matmul, under the MHD loss and SGD."""
+    import dataclasses
+
+    from repro.configs import get_reduced
+    from repro.core.mhd import MHDConfig
+    from repro.core.runtime import DecentralizedTrainer
+    from repro.kernels import ops
+    from repro.lm.pool import lm_client_bundle
+    from repro.models.zoo import build_bundle
+    from repro.optim.optimizers import OptimizerConfig, make_optimizer
+
+    monkeypatch.setattr(ops, "_default_use_pallas", lambda: True)
+    cfg = get_reduced("nemotron3-nano")
+    cfg = dataclasses.replace(
+        cfg, d_model=256, vocab_size=1024, remat="unit",
+        moe=dataclasses.replace(cfg.moe, num_experts=8, d_ff_expert=256,
+                                d_ff_shared=256, experts_held=4,
+                                expert_offset=4))
+    bundle = lm_client_bundle(build_bundle(cfg), 128, 17)
+    trainer = type("T", (), {})()
+    trainer.mhd_cfg = MHDConfig(delta=1, num_aux_heads=cfg.num_aux_heads)
+    trainer.optimizer = make_optimizer(OptimizerConfig())
+    trainer._update_cache = {}
+    update = DecentralizedTrainer._client_update(trainer, bundle)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(trainer.optimizer.init, params)
+    batch = {"tokens": s((1, 256), jnp.int32)}
+    teachers = {"embedding": s((1, 128, 256)), "logits": s((1, 128, 1024)),
+                "aux_logits": s((1, cfg.num_aux_heads, 128, 1024))}
+    text = update.lower(
+        jax.tree.map(lambda x: s(x.shape, x.dtype), params),
+        jax.tree.map(lambda x: s(x.shape, x.dtype), opt), batch, batch,
+        teachers, s((), jnp.int32), s((2,), jnp.uint32)).compile().as_text()
+    assert "%moe_gmm" in text and "%moe_tgmm" in text
